@@ -182,6 +182,9 @@ def conv_rows(fast: bool = False):
 def main():
     import sys
 
+    from repro.launch.jit_cache import enable_compile_cache
+
+    enable_compile_cache()
     fast = "--fast" in sys.argv
     print("name,us_per_call,derived")
     for r in conv_rows(fast=fast):

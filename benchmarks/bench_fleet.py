@@ -165,6 +165,9 @@ def fl_no_arch(fl: dict) -> dict:
 def main():
     import sys
 
+    from repro.launch.jit_cache import enable_compile_cache
+
+    enable_compile_cache()
     fast = "--fast" in sys.argv
     print("name,us_per_call,derived")
     for r in fleet_rows(fast=fast):

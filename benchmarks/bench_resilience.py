@@ -248,6 +248,9 @@ def resilience_rows(fast: bool = False) -> list:
 def main():
     import sys
 
+    from repro.launch.jit_cache import enable_compile_cache
+
+    enable_compile_cache()
     fast = "--fast" in sys.argv
     print("name,us_per_call,derived")
     for r in resilience_rows(fast=fast):

@@ -563,6 +563,9 @@ def serve_rows(fast: bool = False):
 def main():
     import sys
 
+    from repro.launch.jit_cache import enable_compile_cache
+
+    enable_compile_cache()
     fast = "--fast" in sys.argv
     if "--continuous" in sys.argv:
         # CI fast lane: only the continuous-vs-bucket comparison, with the

@@ -19,6 +19,9 @@ def _run(name, fn, *args, **kw):
 
 def main() -> None:
     sys.path.insert(0, os.path.dirname(__file__))
+    from repro.launch.jit_cache import enable_compile_cache
+
+    enable_compile_cache()
     from paper_tables import (api_claims, fig8_storage, fig9_energy,
                               fig10_performance, intermittency_study,
                               kernel_bench, table1_accuracy,

@@ -392,14 +392,17 @@ def get_target(name: str) -> HardwareTarget:
 
 
 def target_for_backend(backend: str) -> ComputeTarget:
-    """The compute target serving a jax backend string.  Unlike
-    :func:`get_target` this never raises: any backend we have no dedicated
-    table for (e.g. an exotic PJRT plugin) gets the conservative CPU
-    dispatch rules, matching the historical non-TPU branch."""
+    """The compute target serving a jax backend string (``gpu`` takes the
+    CPU tables).  A backend with no dispatch table raises ValueError: its
+    engines and crossovers were never chosen, so serving it on another
+    backend's table would hide the device behind the wrong engines."""
     t = _REGISTRY.get(_ALIASES.get(backend, backend))
-    if isinstance(t, ComputeTarget):
-        return t
-    return _REGISTRY["cpu"]
+    if not isinstance(t, ComputeTarget):
+        compute = sorted(n for n, v in _REGISTRY.items()
+                         if isinstance(v, ComputeTarget))
+        raise ValueError(f"no compute target for jax backend {backend!r}; "
+                         f"compute targets: {', '.join(compute)}")
+    return t
 
 
 # Energy scale per PIM design + Table II / §III-E areas.  The values live

@@ -170,15 +170,34 @@ def quant_dense_forward(
     return out.reshape(lead + (w.shape[-1],))
 
 
+def epilogue_scalars(s_w, z_w, a_bits: int):
+    """``(half_scale, zero2)`` for :func:`dequant_epilogue`: the f32 factor
+    ``s_a*s_w/2`` and the integer ``2*z_w``.
+
+    Every DoReFa zero point is a half-integer (``z_w = (2^w_bits - 1)/2``,
+    ``core.quant.weight_levels``), so ``2*z_w`` is an exact integer and the
+    whole affine correction can run in int32."""
+    s_a = jnp.asarray(1.0 / ((1 << a_bits) - 1), jnp.float32)
+    half = (s_a * jnp.asarray(s_w, jnp.float32)) * 0.5
+    zero2 = jnp.round(2.0 * jnp.asarray(z_w, jnp.float32)).astype(jnp.int32)
+    return half, zero2
+
+
 def dequant_epilogue(acc, a_lv, s_w, z_w, a_bits: int, out_dtype=jnp.float32):
     """Affine-correction + dequant for the unsigned (DoReFa) level GEMM:
-    ``out = s_a*s_w*acc − s_a*s_w*z_w*rowsum(A)``.  Single source of truth —
-    the fused Pallas kernel mirrors this expression, and the bit-identity
-    tests rely on every unfused path sharing it."""
-    s_a = jnp.asarray(1.0 / ((1 << a_bits) - 1), out_dtype)
-    acc = acc.astype(out_dtype)
-    rowsum = jnp.sum(a_lv, axis=-1, dtype=jnp.int32).astype(out_dtype)
-    return (s_a * s_w) * acc - (s_a * s_w * z_w) * rowsum[:, None]
+    ``out = (s_a*s_w/2) * (2*acc − 2*z_w*rowsum(A))``.
+
+    The correction is exact int32 arithmetic (wrapping intermediates are
+    harmless: the result is bounded by the accumulator's own range) and the
+    float part is ONE multiply, so there is no multiply-add for a compiler
+    to contract into an FMA: every realization — this one, the fused and
+    implicit Pallas kernels, the direct-conv tail — rounds identically.
+    Single source of truth; the kernels mirror it via
+    :func:`epilogue_scalars`."""
+    half, zero2 = epilogue_scalars(s_w, z_w, a_bits)
+    rowsum = jnp.sum(a_lv, axis=-1, dtype=jnp.int32)
+    e = 2 * acc.astype(jnp.int32) - zero2 * rowsum[:, None]
+    return e.astype(out_dtype) * half.astype(out_dtype)
 
 
 def quant_dense_pre_levels(

@@ -383,7 +383,7 @@ def _layer_weights(p: dict, lp: LayerPlan):
 def execute_cnn_layers(layers, params, x, quant: QuantConfig):
     """Run the compiled layer sequence.  x (B,H,W,C) in [0,1] -> logits."""
     from repro.core.conv_lowering import conv2d_float, quant_conv2d_pre
-    from repro.models.cnn import _norm_act
+    from repro.models.cnn import _norm_act, global_avg_pool
 
     h = x
     last = len(layers) - 1
@@ -405,7 +405,7 @@ def execute_cnn_layers(layers, params, x, quant: QuantConfig):
         if lp.pool:
             h = jax.lax.reduce_window(
                 h, 0.0, jax.lax.add, (1, 2, 2, 1), (1, 2, 2, 1), "VALID") / 4.0
-    return jnp.mean(h, axis=(1, 2))
+    return global_avg_pool(h)
 
 
 def plan_energy_pj(plan: ModelPlan) -> float:

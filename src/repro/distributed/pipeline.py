@@ -16,7 +16,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -74,11 +73,11 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh,
             jnp.where(stage_id == S - 1, outs, jnp.zeros_like(outs)), "pipe")
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P("pipe"), P(None, "data")),
         out_specs=P(None, "data"),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)
 

@@ -104,17 +104,6 @@ def tree_shardings(tree, axes_tree, plan, mesh: Mesh, cfg=None):
     raise TypeError(f"mismatched trees: {type(tree)} vs {type(axes_tree)}")
 
 
-def mesh_context(mesh: Mesh):
-    """Portable ``with mesh:`` context across jax versions.
-
-    ``jax.set_mesh`` only exists on newer jax; on older releases the Mesh
-    object is itself the context manager that installs the global mesh.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def replicated(mesh: Mesh):
     return NamedSharding(mesh, P())
 
@@ -138,10 +127,8 @@ def data_parallel(fn, mesh: Mesh, axis: str = "data"):
     The dispatched batch must be divisible by the axis size — the engine's
     padding buckets guarantee it (`_pad_to` rounds up to the device count).
     """
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=(P(), P(axis)),
-                     out_specs=P(axis), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(), P(axis)),
+                         out_specs=P(axis), check_vma=False)
 
 
 def batch_pspec(plan, ndim: int, batch_dim: int = 0) -> P:

@@ -316,7 +316,7 @@ def attn_flash_pallas(q, k, v, *, causal: bool = True,
                       window: Optional[int] = None, q_bits: int = 8,
                       k_bits: int = 8, block_q: int = 1024,
                       block_kv: int = 1024,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """Single-``pallas_call`` quantized flash attention (shapes as
     :func:`attn_flash_xla`).  The sliding-window variant requires
     ``block_q == block_kv`` (the banded grid slides in whole blocks)."""
@@ -480,13 +480,13 @@ def _paged_kernel(tbl_ref, scal_ref, zint_ref, qpos_ref, q_ref, k_ref,
 
     The KV BlockSpecs are *page-indexed through the scalar-prefetched
     table* (``tbl[b, p]``), so the kernel sees slot b's p-th page as a
-    contiguous block; the null page arrives fully masked (its ppos is all
-    -1).  Online-softmax (m, l, acc) scratch is carried across the inner
-    page dimension, one (S, 128)/(S, hd) row band per query head.
+    contiguous head-major block; the null page arrives fully masked (its
+    ppos is all -1).  Online-softmax (m, l, acc) scratch is carried across
+    the inner page dimension, one (S, 128)/(S, hd) band per query head.
     """
     b, p = pl.program_id(0), pl.program_id(1)
-    S, Hp, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    ps, Hkv = k_ref.shape[1], k_ref.shape[2]
+    Hp, S, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    Hkv, ps = k_ref.shape[1], k_ref.shape[2]
 
     @pl.when(p == 0)
     def _init():
@@ -496,19 +496,19 @@ def _paged_kernel(tbl_ref, scal_ref, zint_ref, qpos_ref, q_ref, k_ref,
 
     z_q, z_k = zint_ref[0], zint_ref[1]
     scal = scal_ref[b]
-    pos = pos_ref[0]                      # (ps,) absolute positions, -1 dead
-    iq = qpos_ref[0]                      # (S,) query positions, -1 dead
-    msk = jnp.broadcast_to(pos[None, :] >= 0, (S, ps))
+    pos = pos_ref[0]                      # (1, ps) key positions, -1 dead
+    iq = qpos_ref[0]                      # (S, 1) query positions, -1 dead
+    msk = jnp.broadcast_to(pos >= 0, (S, ps))
     if causal:
-        msk &= pos[None, :] <= iq[:, None]
+        msk &= pos <= iq
     if window is not None:
-        msk &= pos[None, :] > iq[:, None] - window
+        msk &= pos > iq - window
 
     g = max(n_q_heads // Hkv, 1)
     for j in range(Hp):                   # unrolled: Hp is small & static
         jkv = min(j // g, Hkv - 1)
-        ql = q_ref[0, :, j].astype(jnp.int32)      # (S, hd) levels
-        kl = k_ref[0, :, jkv].astype(jnp.int32)    # (ps, hd)
+        ql = q_ref[0, j].astype(jnp.int32)         # (S, hd) levels
+        kl = k_ref[0, jkv].astype(jnp.int32)       # (ps, hd)
         acc = jnp.zeros((S, ps), jnp.int32)
         for gq, sq in _nibble_split(ql, bits):
             for gk, sk in _nibble_split(kl, bits):
@@ -523,32 +523,29 @@ def _paged_kernel(tbl_ref, scal_ref, zint_ref, qpos_ref, q_ref, k_ref,
                 + hd * z_q * z_k)
         logits = jnp.where(msk, corr.astype(jnp.float32) * scal, NEG_INF)
 
-        r0 = j * S
-        m_old = m_ref[r0:r0 + S, :1]
+        m_old = m_ref[j, :, :1]
         m_new = jnp.maximum(m_old, jnp.max(logits, axis=1, keepdims=True))
         pw = jnp.exp(logits - m_new) * msk
         cf = jnp.exp(m_old - m_new)
-        l_new = l_ref[r0:r0 + S, :1] * cf + jnp.sum(pw, axis=1,
-                                                    keepdims=True)
-        acc_ref[r0:r0 + S] = acc_ref[r0:r0 + S] * cf + jax.lax.dot_general(
-            pw, v_ref[0, :, jkv].astype(jnp.float32),
+        l_new = l_ref[j, :, :1] * cf + jnp.sum(pw, axis=1, keepdims=True)
+        acc_ref[j] = acc_ref[j] * cf + jax.lax.dot_general(
+            pw, v_ref[0, jkv].astype(jnp.float32),
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[r0:r0 + S] = jnp.broadcast_to(m_new, (S, 128))
-        l_ref[r0:r0 + S] = jnp.broadcast_to(l_new, (S, 128))
+        m_ref[j] = jnp.broadcast_to(m_new, (S, 128))
+        l_ref[j] = jnp.broadcast_to(l_new, (S, 128))
 
     @pl.when(p == n_pages - 1)
     def _epilogue():
         for j in range(Hp):
-            r0 = j * S
-            l = jnp.maximum(l_ref[r0:r0 + S, :1], 1e-30)
-            o_ref[0, :, j] = (acc_ref[r0:r0 + S] / l).astype(o_ref.dtype)
+            l = jnp.maximum(l_ref[j, :, :1], 1e-30)
+            o_ref[0, j] = (acc_ref[j] / l).astype(o_ref.dtype)
 
 
 def attn_paged_pallas(q, pool_k, pool_v, ppos, table, q_pos, *,
                       causal: bool = True, window: Optional[int] = None,
                       bits: int = 8, n_q_heads: Optional[int] = None,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """Pallas realization (quantized path only; shapes as
     :func:`attn_paged_xla`).
 
@@ -558,6 +555,11 @@ def attn_paged_pallas(q, pool_k, pool_v, ppos, table, q_pos, *,
     cheap host prepass: s_k is scattered onto the pages through the table
     (each real page has exactly one owner; the null page's winner is
     irrelevant — its ppos keeps it fully masked).
+
+    Every block's last two dims are whole array dims, the TPU tiling rule
+    for blocks narrower than (8, 128): queries and pages go head-major
+    (``(.., H, S|ps, hd)``), query positions a column ``(B, S, 1)``, page
+    positions a row ``(NP+1, 1, ps)``.
     """
     B, S, Hp, hd = q.shape
     NP1, ps, Hkv, _ = pool_k.shape
@@ -578,35 +580,38 @@ def attn_paged_pallas(q, pool_k, pool_v, ppos, table, q_pos, *,
     kernel = functools.partial(
         _paged_kernel, bits=bits, causal=causal, window=window,
         n_q_heads=n_q_heads or Hp, n_pages=P)
+    # index maps take the grid indices first, the prefetched table last
+    page = lambda b, p, tbl: (tbl[b, p], 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, P),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                # scal (B,)
             pl.BlockSpec(memory_space=pltpu.SMEM),                # zint (2,)
-            pl.BlockSpec((1, S), lambda tbl, b, p: (b, 0)),
-            pl.BlockSpec((1, S, Hp, hd), lambda tbl, b, p: (b, 0, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, hd),
-                         lambda tbl, b, p: (tbl[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, hd),
-                         lambda tbl, b, p: (tbl[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps), lambda tbl, b, p: (tbl[b, p], 0)),
+            pl.BlockSpec((1, S, 1), lambda b, p, tbl: (b, 0, 0)),
+            pl.BlockSpec((1, Hp, S, hd), lambda b, p, tbl: (b, 0, 0, 0)),
+            pl.BlockSpec((1, Hkv, ps, hd), page),
+            pl.BlockSpec((1, Hkv, ps, hd), page),
+            pl.BlockSpec((1, 1, ps), lambda b, p, tbl: (tbl[b, p], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, S, Hp, hd), lambda tbl, b, p: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hp, S, hd),
+                               lambda b, p, tbl: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Hp * S, 128), jnp.float32),
-            pltpu.VMEM((Hp * S, 128), jnp.float32),
-            pltpu.VMEM((Hp * S, hd), jnp.float32),
+            pltpu.VMEM((Hp, S, 128), jnp.float32),
+            pltpu.VMEM((Hp, S, 128), jnp.float32),
+            pltpu.VMEM((Hp, S, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, Hp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, S, hd), q.dtype),
         interpret=interpret,
-    )(table.astype(jnp.int32), scal, zint, q_pos.astype(jnp.int32),
-      ql, kl, pool_v, ppos)
-    return out
+    )(table.astype(jnp.int32), scal, zint,
+      q_pos.astype(jnp.int32).reshape(B, S, 1),
+      ql.transpose(0, 2, 1, 3), kl.transpose(0, 2, 1, 3),
+      pool_v.transpose(0, 2, 1, 3), ppos.reshape(NP1, 1, ps))
+    return out.transpose(0, 2, 1, 3)
 
 
 def attn_paged(q, pool_k, pool_v, ppos, table, q_pos, *,

@@ -23,9 +23,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 # VMEM budget per tile (see DESIGN.md): the (TM, TN, TKw) AND intermediate
-# dominates: 64*64*32*4B = 512 KiB, well under ~16 MiB VMEM with
-# double-buffered inputs (m,64,32)+(n,64,32) uint32 tiles.
-TM, TN, TKW = 64, 64, 32
+# dominates: 64*128*128*4B = 4 MiB, under v5e's 16 MiB scoped VMEM with
+# double-buffered inputs (m,64,128)+(n,128,128) uint32 tiles.  TN and TKw
+# are the TPU's 128-lane minimum: Mosaic refuses narrower block widths.
+TM, TN, TKW = 64, 128, 128
 
 
 def _kernel(a_ref, w_ref, o_ref, *, a_bits: int, w_bits: int):

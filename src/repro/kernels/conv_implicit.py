@@ -20,7 +20,7 @@ is the TPU realization of that dataflow:
     round-trip;
   * the PR-1 fused chain rides along unchanged: nibble-split int8 MXU dots
     (operands < 2^7), the in-loop ``rowsum(A)`` EPU pass, and the affine
-    dequant epilogue ``out = s*acc - t*rowsum`` — all inside the same
+    dequant epilogue ``out = s/2 * (2*acc - 2*z_w*rowsum)`` — all inside the same
     ``pallas_call``, one HBM pass over activations.
 
 ``conv_implicit_xla`` is the off-TPU realization of the same contract: the
@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.and_accum import _nibble_split, f32dot_exact
+from repro.core.and_accum import (_nibble_split, epilogue_scalars,
+                                  f32dot_exact)
 from repro.core.conv_lowering import _out_hw, pad_split
 
 TOH, TCOUT = 8, 128
@@ -63,13 +64,16 @@ def implicit_xla_exact(k: int, a_bits: int, w_bits: int) -> bool:
     return _group_max(a_bits) * _group_max(w_bits) * max(k, 1) < (1 << 24)
 
 
-def _kernel(s_ref, x_ref, w_ref, o_ref, *, kh: int, kw: int, cin: int,
+def _kernel(s_ref, z_ref, x_ref, w_ref, o_ref, *, kh: int, kw: int, cin: int,
             stride: int, ow: int, toh: int, a_bits: int, w_bits: int):
     t = pl.program_id(1)
     # halo'd row span for this output-row tile: toh*stride + (kh-1) rows,
     # de-strided below by reshape (no strided memory access)
     span = toh * stride + kh - 1
-    xt = x_ref[0, pl.ds(t * toh * stride, span)]        # (span, Wp, Cin)
+    # widened to int32 on load: Mosaic refuses the patch reshapes below on
+    # packed int8 vectors unless the widths happen to align to its tiling
+    # (e.g. a 27-wide 5x5 AlexNet layer, any stride-2 de-stride)
+    xt = x_ref[0, pl.ds(t * toh * stride, span)].astype(jnp.int32)
 
     tn = o_ref.shape[-1]
     acc = jnp.zeros((toh * ow, tn), jnp.int32)
@@ -80,7 +84,7 @@ def _kernel(s_ref, x_ref, w_ref, o_ref, *, kh: int, kw: int, cin: int,
             rows = rows.reshape(toh, stride, -1, cin)[:, 0]
             cols = rows[:, dx: dx + ow * stride]
             patch = cols.reshape(toh, ow, stride, cin)[:, :, 0]
-            p = patch.reshape(toh * ow, cin).astype(jnp.int32)
+            p = patch.reshape(toh * ow, cin)
             # in-K rowsum(A) — the paper's extra EPU popcount pass, fused
             rs = rs + jnp.sum(p, axis=1, dtype=jnp.int32)[:, None]
             wk = w_ref[(dy * kw + dx) * cin: (dy * kw + dx + 1) * cin, :]
@@ -93,8 +97,7 @@ def _kernel(s_ref, x_ref, w_ref, o_ref, *, kh: int, kw: int, cin: int,
                         preferred_element_type=jnp.int32,
                     )
                     acc = acc + (d << (sa + sw))
-    s, z = s_ref[0], s_ref[1]
-    out = s * acc.astype(jnp.float32) - z * rs.astype(jnp.float32)
+    out = (2 * acc - z_ref[0] * rs).astype(jnp.float32) * s_ref[0]
     o_ref[...] = out.reshape(1, toh, ow, tn)
 
 
@@ -142,9 +145,7 @@ def conv_implicit_pallas(
                          (0, 0)))
     w_p = jnp.pad(w_lv, ((0, 0), (0, coutp - cout)))
 
-    s_a = jnp.asarray(1.0 / ((1 << a_bits) - 1), jnp.float32)
-    s = s_a * s_w.astype(jnp.float32)
-    scales = jnp.stack([s, s * z_w.astype(jnp.float32)])  # (2,) SMEM
+    half, zero2 = epilogue_scalars(s_w, z_w, a_bits)
 
     grid = (b, ohp // toh, coutp // tcout)
     out = pl.pallas_call(
@@ -152,6 +153,7 @@ def conv_implicit_pallas(
                           ow=ow, toh=toh, a_bits=a_bits, w_bits=w_bits),
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             # whole image per batch index: index map ignores (t, j), so the
             # pipelined buffer is fetched once per image and stays resident
@@ -162,7 +164,7 @@ def conv_implicit_pallas(
                                lambda i, t, j: (i, t, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, ohp, ow, coutp), jnp.float32),
         interpret=interpret,
-    )(scales, x_p, w_p)
+    )(half.reshape(1), zero2.reshape(1), x_p, w_p)
     return out[:, :oh, :, :cout]
 
 
@@ -242,13 +244,7 @@ def conv_implicit_xla(
         rowsum = rowsum + (_conv(ga.astype(jnp.float32),
                                  ones).astype(jnp.int32) << sa)
 
-    # same expression (and the same int32 -> f32 accumulator cast) as
-    # core.and_accum.dequant_epilogue, so the COMPILED paths round
-    # identically.  (Eager execution can differ by FMA-contraction ulps —
-    # XLA:CPU fuses this mult/mult/sub into one LLVM loop under jit — so
-    # bit-identity is a jitted-vs-jitted property, which is what serve
-    # runs; tests compare accordingly.)
-    s_a = jnp.asarray(1.0 / ((1 << a_bits) - 1), jnp.float32)
-    s = s_a * s_w.astype(jnp.float32)
-    return (s * acc.astype(jnp.float32)
-            - (s * z_w.astype(jnp.float32)) * rowsum.astype(jnp.float32))
+    # core.and_accum.dequant_epilogue's expression: exact int32 correction,
+    # then one f32 multiply (nothing for a compiler to contract into an FMA)
+    half, zero2 = epilogue_scalars(s_w, z_w, a_bits)
+    return (2 * acc - zero2 * rowsum).astype(jnp.float32) * half

@@ -17,7 +17,8 @@ passes (quantize, GEMM, epilogue).  This kernel does all of it in one
   3. the in-K-loop ``rowsum(A)`` accumulation (the paper's extra EPU popcount
      pass, here a VPU reduction riding the same VMEM residency);
   4. the affine-correction + dequant epilogue
-     ``out = (s_a*s_w) * acc - (s_a*s_w*z_w) * rowsum`` on the last K step.
+     ``out = (s_a*s_w/2) * (2*acc - 2*z_w*rowsum)`` on the last K step
+     (int32 correction, one f32 multiply: ``and_accum.dequant_epilogue``).
 
 Weights arrive PRE-QUANTIZED as int8 levels (``core/prequant.py`` — the
 checkpoint-resident C_n(W)); the float weights, the per-call
@@ -38,12 +39,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.and_accum import _nibble_split
+from repro.core.and_accum import _nibble_split, epilogue_scalars
 
 TM, TN, TK = 128, 128, 512
 
 
-def _kernel(s_ref, a_ref, w_ref, o_ref, acc_ref, rs_ref, *,
+def _kernel(s_ref, z_ref, a_ref, w_ref, o_ref, acc_ref, rs_ref, *,
             a_bits: int, w_bits: int, a_is_levels: bool, nk: int):
     k = pl.program_id(2)
 
@@ -79,12 +80,12 @@ def _kernel(s_ref, a_ref, w_ref, o_ref, acc_ref, rs_ref, *,
             acc = acc + (d << (sa + sw))
     acc_ref[...] = acc
 
-    # (4) affine-correction + dequant epilogue, once per output tile
+    # (4) affine-correction + dequant epilogue, once per output tile —
+    # and_accum.dequant_epilogue's exact int32 correction + one f32 multiply
     @pl.when(k == nk - 1)
     def _epilogue():
-        s, t = s_ref[0], s_ref[1]
-        o_ref[...] = (s * acc_ref[...].astype(jnp.float32)
-                      - t * rs_ref[...].astype(jnp.float32))
+        e = 2 * acc_ref[...] - z_ref[0] * rs_ref[...]
+        o_ref[...] = e.astype(jnp.float32) * s_ref[0]
 
 
 def _pad(x, mult, axis):
@@ -122,9 +123,7 @@ def fused_qgemm_pallas(
     """
     M, K = a.shape
     N = w_lv.shape[1]
-    s_a = jnp.asarray(1.0 / ((1 << a_bits) - 1), jnp.float32)
-    s = s_a * s_w.astype(jnp.float32)
-    scales = jnp.stack([s, s * z_w.astype(jnp.float32)])  # (2,) SMEM
+    half, zero2 = epilogue_scalars(s_w, z_w, a_bits)
     a_p = _pad(_pad(a, tm, 0), tk, 1)
     w_p = _pad(_pad(w_lv, tk, 0), tn, 1)
     Mp, Kp = a_p.shape
@@ -137,6 +136,7 @@ def fused_qgemm_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((tm, tk), lambda i, j, k: (i, k)),
             pl.BlockSpec((tk, tn), lambda i, j, k: (k, j)),
         ],
@@ -147,5 +147,5 @@ def fused_qgemm_pallas(
             pltpu.VMEM((tm, tn), jnp.int32),  # lane-broadcast rowsum(A)
         ],
         interpret=interpret,
-    )(scales, a_p, w_p)
+    )(half.reshape(1), zero2.reshape(1), a_p, w_p)
     return out[:M, :N]

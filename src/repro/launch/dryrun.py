@@ -54,8 +54,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool = False,
     t0 = time.time()
     from repro.models.layers import set_static_act_scale
     set_static_act_scale(getattr(cfg, "act_scale", 0.0))
-    from repro.distributed.sharding import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         built = steps_mod.build_cell(
             cfg, cell, plan, mesh,
             qmode="serve" if (quant and cell.kind != "train") else "train",

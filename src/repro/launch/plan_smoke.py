@@ -4,13 +4,16 @@ in the reloading process.
 
   PYTHONPATH=src python -m repro.launch.plan_smoke [--out results/plan_cache/plan_smoke]
 
-The parent process compiles a CNN ModelPlan (with a small autotune pass),
-saves it plus the expected logits, then spawns a child interpreter that
-reloads the plan from disk and serves.  The child patches
-``repro.core.quant.weight_levels`` to raise — proving the reload path never
-requantizes — and asserts the logits match bit-for-bit.  If ``--out``
-already holds a valid plan for the same fingerprintable inputs (the CI
-plan-artifact cache), compilation is skipped and only the reload gate runs.
+The parent never imports JAX (on a TPU the process that touches JAX holds
+the chip, and a child would then fail or hang).  It runs two children, one
+after the other: the compile child builds a CNN ModelPlan (with a small
+autotune pass), saves it plus the expected logits; the reload child
+reloads the plan from disk and serves.  The reload child patches
+``repro.core.quant.weight_levels`` to raise — proving the reload path
+never requantizes — and asserts the logits match bit-for-bit.  If
+``--out`` already holds a valid plan for the same fingerprintable inputs
+(the CI plan-artifact cache), compilation is skipped and only the reload
+gate runs.
 """
 from __future__ import annotations
 
@@ -68,15 +71,9 @@ def check(base: str) -> int:
     return 0
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--out", default="results/plan_cache/plan_smoke")
-    ap.add_argument("--check", default=None, metavar="BASE",
-                    help="internal: run the fresh-process reload gate")
-    args = ap.parse_args()
-    if args.check:
-        return check(args.check)
-
+def compile_and_save(base: str) -> int:
+    """Compile child: compile (or reuse) the plan, save the expected
+    logits, and check them against the legacy auto-dispatch forward."""
     import jax
     import numpy as np
 
@@ -84,7 +81,6 @@ def main() -> int:
         save_plan
 
     spec, params, x, quant = _setup()
-    base = args.out
     reused = False
     recompile_reason = None
     if os.path.exists(base + ".json") and os.path.exists(
@@ -120,24 +116,50 @@ def main() -> int:
     legacy = np.asarray(jax.jit(
         lambda v: cnn_forward(plan.params, v, spec, quant, "serve"))(x))
     np.testing.assert_array_equal(expected, legacy)
-
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-        + os.pathsep + env.get("PYTHONPATH", ""))
-    p = subprocess.run(
-        [sys.executable, "-m", "repro.launch.plan_smoke", "--check", base],
-        env=env, capture_output=True, text=True, timeout=600)
-    sys.stdout.write(p.stdout)
-    sys.stderr.write(p.stderr)
-    if p.returncode != 0 or "PLAN SMOKE OK" not in p.stdout:
-        print("PLAN SMOKE FAILED", file=sys.stderr)
-        return 1
     print(json.dumps(dict(
         plan=base + ".json", reused_cached_artifact=reused,
         recompile_reason=recompile_reason,
         fingerprint=plan.fingerprint(),
         engines={lp.name: lp.engine for lp in plan.layers})))
+    return 0
+
+
+def _child(flag: str, base: str) -> subprocess.CompletedProcess:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+        + os.pathsep + env.get("PYTHONPATH", ""))
+    p = subprocess.run(
+        [sys.executable, "-m", "repro.launch.plan_smoke", flag, base],
+        env=env, capture_output=True, text=True, timeout=600)
+    sys.stdout.write(p.stdout)
+    sys.stderr.write(p.stderr)
+    return p
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--out", default="results/plan_cache/plan_smoke")
+    ap.add_argument("--compile", default=None, metavar="BASE",
+                    help="internal: run the compile child")
+    ap.add_argument("--check", default=None, metavar="BASE",
+                    help="internal: run the fresh-process reload gate")
+    args = ap.parse_args()
+    if args.compile or args.check:
+        from repro.launch.jit_cache import enable_compile_cache
+
+        enable_compile_cache()
+        if args.compile:
+            return compile_and_save(args.compile)
+        return check(args.check)
+
+    if _child("--compile", args.out).returncode != 0:
+        print("PLAN SMOKE FAILED (compile)", file=sys.stderr)
+        return 1
+    p = _child("--check", args.out)
+    if p.returncode != 0 or "PLAN SMOKE OK" not in p.stdout:
+        print("PLAN SMOKE FAILED", file=sys.stderr)
+        return 1
     return 0
 
 

@@ -356,7 +356,9 @@ def main():
                     help="--chaos-mtbf: decode epoch checkpoint directory "
                          "(default: a fresh temp dir)")
     args = ap.parse_args()
+    from repro.launch.jit_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

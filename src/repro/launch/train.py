@@ -71,8 +71,7 @@ def main():
                 jax.random.PRNGKey(s), (args.batch, cfg.n_patches, cfg.vit_dim))
         return out
 
-    from repro.distributed.sharding import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         tr.run(bf)
 
 

@@ -113,6 +113,26 @@ def _norm_act(x, g, beta, quant: QuantConfig, role: str, mode: str = "train"):
     return quantize_activation(x, quant.a_bits)
 
 
+def global_avg_pool(h):
+    """Spatial mean (B, H, W, C) -> (B, C) as a fixed pairwise tree of
+    elementwise adds.
+
+    A reduce op leaves its summation order to the compiler, which picks it
+    per program: XLA:CPU sums the last layer's outputs in another order
+    when the weights are jit constants than when they are arguments, and
+    the logits then differ in the last bit.  Elementwise adds are never
+    reassociated, so this order, and every logit, is the same in each
+    program that serves the model (batched, per-request, or closed over
+    its weights)."""
+    b, hh, ww, c = h.shape
+    f = h.reshape(b, hh * ww, c)
+    while f.shape[1] > 1:
+        half = f.shape[1] // 2
+        s = f[:, :half] + f[:, half: 2 * half]
+        f = jnp.concatenate([s, f[:, 2 * half:]], axis=1)
+    return f[:, 0] / (hh * ww)
+
+
 def cnn_forward(params, x, spec: Sequence[ConvSpec], quant: QuantConfig,
                 mode: str = "train", g_key=None):
     """x (B,H,W,3) in [0,1]. Returns logits (B, n_classes).
@@ -151,7 +171,7 @@ def cnn_forward(params, x, spec: Sequence[ConvSpec], quant: QuantConfig,
         if s.pool:
             h = jax.lax.reduce_window(
                 h, 0.0, jax.lax.add, (1, 2, 2, 1), (1, 2, 2, 1), "VALID") / 4.0
-    return jnp.mean(h, axis=(1, 2))  # global average -> (B, classes)
+    return global_avg_pool(h)  # -> (B, classes)
 
 
 def cnn_loss(params, batch, spec, quant: QuantConfig, g_key=None):

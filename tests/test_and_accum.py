@@ -6,7 +6,7 @@ dequantized GEMM must match the quantize->float-matmul oracle.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import and_accum, bitplane
 from repro.core.quant import activation_levels_signed, weight_levels

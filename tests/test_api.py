@@ -56,9 +56,11 @@ def test_registry_contents_and_aliases():
     assert api.get_target("proposed") is api.get_target("sot_mram")
     assert api.get_target("asic") is api.get_target("cmos_asic")
     assert api.target_for_backend("gpu") is api.get_target("cpu")
-    # unknown backends fall back to conservative CPU dispatch (historical
-    # non-TPU branch), while get_target stays strict
-    assert api.target_for_backend("weird_pjrt") is api.get_target("cpu")
+    # a backend with no dispatch table is refused, never served on the
+    # CPU tables; a PIM design is no serve backend either
+    for backend in ("weird_pjrt", "sot_mram"):
+        with pytest.raises(ValueError, match="no compute target"):
+            api.target_for_backend(backend)
     kinds = {n: api.get_target(n).kind for n in api.available_targets()}
     assert kinds["cpu"] == kinds["tpu"] == "compute"
     assert kinds["sot_mram"] == kinds["reram"] == "pim"

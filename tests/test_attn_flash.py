@@ -18,6 +18,7 @@ Pins five contracts:
   serializes it, and a reloaded plan dispatches it by table lookup.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ import pytest
 
 from repro.kernels import ops
 from repro.kernels.attn_flash import (attn_flash_pallas, attn_flash_xla,
+                                      attn_paged_pallas, attn_paged_xla,
                                       attn_quant_scale, flash_error_bound,
                                       flash_levels_exact, _levels)
 from repro.models.layers import (_chunk_plan, _mask, attn_banded,
@@ -76,7 +78,8 @@ def test_flash_faithful_to_quantized_reference(q_bits, k_bits, causal,
     q, k, v = _qkv(100)
     ref = _ref_quant_full(q, k, v, causal=causal, window=window,
                           q_bits=q_bits, k_bits=k_bits)
-    for fn in (attn_flash_xla, attn_flash_pallas):
+    for fn in (attn_flash_xla,
+               functools.partial(attn_flash_pallas, interpret=True)):
         out = fn(q, k, v, causal=causal, window=window, q_bits=q_bits,
                  k_bits=k_bits, block_q=32, block_kv=32)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -195,6 +198,48 @@ def test_expand_kv_tp_padded_heads():
     for j, src in enumerate([0, 0, 1, 1, 1, 1]):
         np.testing.assert_array_equal(np.asarray(ke[:, :, j]),
                                       np.asarray(k[:, :, src]))
+
+
+# ---------------------------------------------------------------------------
+# Paged attention: the Pallas kernel against its gather realization
+# ---------------------------------------------------------------------------
+
+def _paged_problem(S, heads, kv_heads, hd=16, ps=8, n_pages=6, seed=0):
+    """Two slots over a 6-page pool (+ null page 6).  Slot 0 owns pages
+    [3, 1] with positions 0..11 written (page 1 ragged); slot 1 owns page
+    [0] with positions 0..4.  Unowned pages hold stale K/V and stale
+    positions, which the table must never expose."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    null = n_pages
+    pool_k = jax.random.normal(ks[0], (n_pages + 1, ps, kv_heads, hd))
+    pool_v = jax.random.normal(ks[1], (n_pages + 1, ps, kv_heads, hd))
+    ppos = np.full((n_pages + 1, ps), -1, np.int32)
+    ppos[3] = np.arange(8)
+    ppos[1, :4] = np.arange(8, 12)
+    ppos[0, :5] = np.arange(5)
+    ppos[2] = np.arange(40, 48)        # stale tenant, not in any table
+    table = np.asarray([[3, 1, null], [0, null, null]], np.int32)
+    last = np.asarray([11, 4], np.int32)
+    q_pos = last[:, None] - (S - 1) + np.arange(S, dtype=np.int32)[None]
+    q = jax.random.normal(ks[2], (2, S, heads, hd))
+    return q, pool_k, pool_v, jnp.asarray(ppos), jnp.asarray(table), \
+        jnp.asarray(q_pos)
+
+
+@pytest.mark.parametrize("S,heads,kv_heads", [(1, 4, 2), (4, 3, 3),
+                                               (4, 6, 2)])
+def test_paged_pallas_matches_gather_realization(S, heads, kv_heads):
+    """The Pallas paged kernel (interpret mode) computes the same
+    attention as ``attn_paged_xla``: exact int32 logits, so only the
+    online softmax's f32 summation order separates them — decode (S=1)
+    and prefill-chunk (S=4) shapes, GQA, ragged pages, null-page padding
+    and stale unowned pages."""
+    args = _paged_problem(S, heads, kv_heads)
+    ref = attn_paged_xla(*args, quantized=True, bits=8, n_q_heads=heads)
+    out = attn_paged_pallas(*args, bits=8, n_q_heads=heads, interpret=True)
+    assert out.shape == ref.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=0)
 
 
 # ---------------------------------------------------------------------------

@@ -147,7 +147,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import make_pipeline_mesh, pipeline_apply
-from repro.distributed.sharding import mesh_context
 
 S, M, mb, d = 4, 8, 2, 16
 mesh = make_pipeline_mesh(S, data=2)
@@ -158,7 +157,7 @@ def stage_fn(w, x):
     return jnp.tanh(x @ w)
 
 x = jax.random.normal(key, (M, mb, d))
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     y = pipeline_apply(stage_fn, Ws, x, mesh=mesh, n_microbatches=M)
 # oracle: sequential application of all stages
 ref = x

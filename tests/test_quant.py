@@ -2,7 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.quant import (
     PAPER_CONFIGS, QuantConfig, activation_levels, activation_levels_signed,

@@ -6,6 +6,9 @@ gradients and checkpoint/resume); prefill+decode serving is consistent
 with teacher forcing.
 """
 import dataclasses
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -137,3 +140,35 @@ def test_prequantized_serving_matches_runtime_quant():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2.0,
                                rtol=0.5)
     assert pq["blocks"]["attn"]["attn"]["wq"]["q"].dtype == jnp.int8
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_compile_cache_dir(monkeypatch):
+    """Entry points keep JAX's persistent cache at JAX_COMPILATION_CACHE_DIR
+    when set (and then change nothing), else at the fixed in-checkout
+    directory."""
+    from repro.launch import jit_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "cache-from-env")
+    assert jit_cache.enable_compile_cache() == "cache-from-env"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        got = jit_cache.enable_compile_cache()
+        assert got == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_chip_smoke_refuses_cpu():
+    """Without a TPU the chip smoke test fails and prints no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert "no TPU found" in p.stderr
+    assert '"ok"' not in p.stdout
