@@ -1,0 +1,11 @@
+"""Share of the traced window in which no operation ran on the device
+(1 - the union of busy intervals over the window), averaged over chips."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    busy = run.trace.busy_s()
+    if busy is None:
+        return None
+    return 100.0 * (1.0 - busy / run.trace.window_s)
