@@ -110,6 +110,12 @@ class Deployment:
     def drain(self):
         return self.engine.drain()
 
+    def record_spans(self):
+        """Turn on the engine's in-memory span recorder and return it
+        (:class:`repro.launch.engine.SpanRecorder`; the resilient engine
+        records none)."""
+        return self.engine.record_spans()
+
     @property
     def stats(self) -> dict:
         return self.engine.stats

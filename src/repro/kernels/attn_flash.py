@@ -375,6 +375,7 @@ def attn_flash_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((tq, 128), jnp.float32),
             pltpu.VMEM((tq, hd), jnp.float32),
         ],
+        name="attn_flash",
         interpret=interpret,
     )(scal, zint, ql, kl, vp)
     out = out.reshape(B, H, Sq_p, hd).transpose(0, 2, 1, 3)
@@ -606,6 +607,7 @@ def attn_paged_pallas(q, pool_k, pool_v, ppos, table, q_pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hp, S, hd), q.dtype),
+        name="attn_paged",
         interpret=interpret,
     )(table.astype(jnp.int32), scal, zint,
       q_pos.astype(jnp.int32).reshape(B, S, 1),

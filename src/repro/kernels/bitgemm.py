@@ -87,6 +87,7 @@ def bitgemm_packed_pallas(
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
+        name="bitgemm",
         interpret=interpret,
     )(a_p, w_p)
     return out[:M, :N]

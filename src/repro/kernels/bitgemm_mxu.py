@@ -71,6 +71,7 @@ def int8_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
+        name="bitgemm_mxu",
         interpret=interpret,
     )(a_p, b_p)
     return out[:M, :N]

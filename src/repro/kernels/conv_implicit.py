@@ -163,6 +163,7 @@ def conv_implicit_pallas(
         out_specs=pl.BlockSpec((1, toh, ow, tcout),
                                lambda i, t, j: (i, t, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, ohp, ow, coutp), jnp.float32),
+        name="conv_implicit",
         interpret=interpret,
     )(half.reshape(1), zero2.reshape(1), x_p, w_p)
     return out[:, :oh, :, :cout]

@@ -146,6 +146,7 @@ def fused_qgemm_pallas(
             pltpu.VMEM((tm, tn), jnp.int32),  # int32 accumulator
             pltpu.VMEM((tm, tn), jnp.int32),  # lane-broadcast rowsum(A)
         ],
+        name="fused_qgemm",
         interpret=interpret,
     )(half.reshape(1), zero2.reshape(1), a_p, w_p)
     return out[:M, :N]

@@ -66,6 +66,7 @@ def quantize_pack_pallas(
             jax.ShapeDtypeStruct((Mp, Kp), jnp.int32),
             jax.ShapeDtypeStruct((bits, Mp, Kp // LANE), jnp.uint32),
         ],
+        name="quantpack",
         interpret=interpret,
     )(a_p)
     kw = -(-K // LANE)

@@ -67,6 +67,9 @@ class Result:
     batch: int    # real co-batched requests in the dispatch
     padded: int   # dispatched batch after padding
     t_start: float = 0.0  # when the engine began computing this request
+    # engine-clock time at which each token reached host state (LM engines;
+    # empty for ServeEngine results)
+    token_times: tuple = ()
 
     @property
     def latency_s(self) -> float:
@@ -126,6 +129,49 @@ class BucketBatcher:
         reqs = self._open.pop(key)
         self._opened_at.pop(key, None)
         return Bucket(key, reqs)
+
+
+class SpanRecorder:
+    """Host spans of one engine, kept in memory on the engine's clock.
+
+    ``records`` maps a span name to its records, each
+    ``{"t": start, "dt": seconds, "id": n, "parent": id | None, **attrs}``;
+    ``parent`` is the span open around it.  The engines add their bucket
+    (``ServeEngine``) or decode step (``ContinuousLMEngine``) and counts as
+    attributes.  Nothing is written anywhere: the caller reads ``records``.
+
+    An engine holds ``None`` until its ``record_spans()`` is called, so a
+    recorder that is off costs one ``is None`` test per span boundary.
+    """
+
+    def __init__(self, clock: Callable[[], float]):
+        self.clock = clock
+        self.records: dict[str, list[dict]] = {}
+        self._open: list[int] = []
+        self._next_id = 0
+
+    def begin(self, t: float | None = None) -> tuple[int, float]:
+        """Open a span at ``t`` (default: now); returns its (id, start)."""
+        sid = self._next_id
+        self._next_id += 1
+        self._open.append(sid)
+        return sid, self.clock() if t is None else t
+
+    def end(self, name: str, span: tuple[int, float],
+            t: float | None = None, **attrs) -> float:
+        """Close ``span`` at ``t`` (default: now) and record it; spans left
+        open inside it close with it.  Returns the end time."""
+        sid, t0 = span
+        t1 = self.clock() if t is None else t
+        del self._open[self._open.index(sid):]
+        parent = self._open[-1] if self._open else None
+        self.records.setdefault(name, []).append(
+            dict(t=t0, dt=t1 - t0, id=sid, parent=parent, **attrs))
+        return t1
+
+    def abandon(self) -> None:
+        """Forget the open spans (an exception unwound past them)."""
+        self._open.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -362,6 +408,16 @@ class ServeEngine(_SubmitRetryMixin):
         else:
             self._params = jax.device_put(runner.params)
         self.stats = dict(dispatches=0, requests=0, padded_rows=0)
+        self.spans: SpanRecorder | None = None
+
+    def record_spans(self) -> SpanRecorder:
+        """Turn on the span recorder (off by default) and return it.  Per
+        bucket: ``serve.stage`` (``serve.collate``, ``serve.put``),
+        ``serve.dispatch`` and ``serve.harvest`` (``serve.wait``,
+        ``serve.split``), each carrying the bucket's id."""
+        if self.spans is None:
+            self.spans = SpanRecorder(self.clock)
+        return self.spans
 
     # -- queue side ---------------------------------------------------------
 
@@ -466,39 +522,66 @@ class ServeEngine(_SubmitRetryMixin):
         return self._fns[cache_key]
 
     def _stage(self, bucket: Bucket):
-        """Start the host->device transfer for one bucket (async)."""
-        padded = self._pad_to(len(bucket.requests))
+        """Start the host->device transfer for one bucket (async).  The
+        bucket's id is its ``serve.stage`` span's (None, recorder off)."""
+        rec, bid = self.spans, None
+        if rec is not None:
+            stage = rec.begin()
+            bid = stage[0]
+            collate = rec.begin(stage[1])
+        n = len(bucket.requests)
+        padded = self._pad_to(n)
         batch = self.runner.collate([r.payload for r in bucket.requests],
                                     padded)
+        if rec is not None:
+            t = rec.end("serve.collate", collate, bucket=bid, batch=n,
+                        padded=padded)
+            put = rec.begin(t)
         if self.mesh is not None:
             from repro.distributed.sharding import batch_sharding
             dev = jax.device_put(batch, batch_sharding(self.mesh))
         else:
             dev = jax.device_put(batch)
-        return bucket, padded, dev
+        if rec is not None:
+            t = rec.end("serve.put", put, bucket=bid, bytes=int(batch.nbytes))
+            rec.end("serve.stage", stage, t, bucket=bid)
+        return bucket, padded, dev, bid
 
     def _execute(self, buckets: list[Bucket]) -> None:
         """Pipelined bucket loop: dispatch bucket i, then stage bucket i+1
         (H2D overlaps i's compute), then harvest bucket i-1 (its compute
         overlapped with i's dispatch).  At most two buckets in flight."""
+        rec = self.spans
         staged = self._stage(buckets[0]) if buckets else None
         inflight = None
         for i in range(len(buckets)):
-            bucket, padded, dev = staged
+            bucket, padded, dev, bid = staged
             t_start = self.clock()
+            if rec is not None:
+                dispatch, n_fns = rec.begin(t_start), len(self._fns)
             out = self._executable(bucket.key, padded)(self._params, dev)
+            if rec is not None:
+                rec.end("serve.dispatch", dispatch, bucket=bid,
+                        built=len(self._fns) > n_fns)
             staged = self._stage(buckets[i + 1]) if i + 1 < len(buckets) else None
             if inflight is not None:
                 self._harvest(*inflight)
-            inflight = (bucket, padded, out, t_start)
+            inflight = (bucket, padded, out, t_start, bid)
         if inflight is not None:
             self._harvest(*inflight)
 
     def _harvest(self, bucket: Bucket, padded: int, out,
-                 t_start: float) -> None:
+                 t_start: float, bid: int | None) -> None:
+        rec = self.spans
+        if rec is not None:
+            harvest = rec.begin()
+            wait = rec.begin(harvest[1])
         host = np.asarray(out)  # blocks until this bucket's compute is done
         n = len(bucket.requests)
         t_done = self.clock()
+        if rec is not None:
+            rec.end("serve.wait", wait, t_done, bucket=bid)
+            split = rec.begin(t_done)
         for req, val in zip(bucket.requests, self.runner.split(host, n)):
             self._results[req.rid] = Result(req.rid, val, req.t_submit,
                                             t_done, n, padded,
@@ -506,6 +589,9 @@ class ServeEngine(_SubmitRetryMixin):
         self.stats["dispatches"] += 1
         self.stats["requests"] += n
         self.stats["padded_rows"] += padded - n
+        if rec is not None:
+            t = rec.end("serve.split", split, bucket=bid)
+            rec.end("serve.harvest", harvest, t, bucket=bid)
 
 
 # ---------------------------------------------------------------------------
@@ -533,6 +619,7 @@ class _Slot:
     pos: int                # next KV position to write (tokens inserted)
     emitted: list           # generated tokens so far (first from prefill)
     last_tok: int           # last generated token (next decode input)
+    times: list = dataclasses.field(default_factory=list)  # token stamps
 
 
 class ContinuousLMEngine(_SubmitRetryMixin):
@@ -650,6 +737,7 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         self.stats = dict(dispatches=0, requests=0, padded_rows=0, steps=0,
                           admissions=0, retirements=0, prefill_chunks=0,
                           dead_lettered=0, commits=0, power_losses=0)
+        self.spans: SpanRecorder | None = None
 
         self.epoch_steps = max(int(epoch_steps), 1)
         self._last_commit: int | None = None
@@ -659,6 +747,16 @@ class ContinuousLMEngine(_SubmitRetryMixin):
             self.ckpt = Checkpointer(checkpoint_dir, keep=2,
                                      async_save=False)
             self._try_restore()  # resume a prior engine's in-flight state
+
+    def record_spans(self) -> SpanRecorder:
+        """Turn on the span recorder (off by default) and return it:
+        ``lm.admit`` (``lm.reset_pages``, ``lm.prefill_chunk``),
+        ``lm.decode_step`` (each step span holds ``lm.dispatch`` and
+        ``lm.logits_wait``) and ``lm.commit``, each carrying ``step``, the
+        decode steps run before it."""
+        if self.spans is None:
+            self.spans = SpanRecorder(self.clock)
+        return self.spans
 
     # -- compiled programs ---------------------------------------------------
 
@@ -687,6 +785,9 @@ class ContinuousLMEngine(_SubmitRetryMixin):
                   pos: np.ndarray, valid: np.ndarray) -> np.ndarray:
         """Run one paged model step; adopts the updated pools.  Returns
         host logits (B, S, vocab)."""
+        rec = self.spans
+        if rec is not None:
+            span = rec.begin()
         b = table_rows.shape[0]
         tbl = jnp.broadcast_to(
             jnp.asarray(table_rows, jnp.int32)[None],
@@ -697,19 +798,30 @@ class ContinuousLMEngine(_SubmitRetryMixin):
             jnp.asarray(toks, jnp.int32), jnp.asarray(pos, jnp.int32),
             jnp.asarray(valid, jnp.int32))
         self.stats["dispatches"] += 1
-        return np.asarray(logits)
+        if rec is None:
+            return np.asarray(logits)
+        t = rec.end("lm.dispatch", span, step=self._step)
+        wait = rec.begin(t)
+        host = np.asarray(logits)
+        rec.end("lm.logits_wait", wait, step=self._step)
+        return host
 
     def _reset_pages(self, pages: list) -> None:
         """Mark freshly-allocated pages never-written (ppos = -1) so stale
         positions from a prior tenant can't unmask its keys.  The page
         list pads to a fixed width with the out-of-bounds drop index, so
         this stays one compiled program."""
+        rec = self.spans
+        if rec is not None:
+            span = rec.begin()
         drop = self.pool.num_pages + 1
         padded = np.full((self.table_pages,), drop, np.int32)
         padded[: len(pages)] = pages
         self.program_shapes.add(("reset",))
         self._pools["ppos"] = self._reset_fn(self._pools["ppos"],
                                              jnp.asarray(padded))
+        if rec is not None:
+            rec.end("lm.reset_pages", span, step=self._step, pages=len(pages))
 
     # -- queue side ----------------------------------------------------------
 
@@ -759,6 +871,7 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         skip-ahead) — determinism over utilization."""
         from repro.core.kv_pages import PoolExhausted, pages_needed
 
+        rec = self.spans
         while self._waiting:
             slot_i = self._free_slot()
             if slot_i is None:
@@ -770,6 +883,8 @@ class ContinuousLMEngine(_SubmitRetryMixin):
                 pages = self.pool.alloc(need)
             except PoolExhausted:
                 return
+            if rec is not None:
+                span = rec.begin()
             self._waiting.popleft()
             self._reset_pages(pages)
             self._table[slot_i, :] = self.pool.null_page
@@ -779,18 +894,25 @@ class ContinuousLMEngine(_SubmitRetryMixin):
             self._slots[slot_i] = s
             self.stats["admissions"] += 1
             self._prefill(slot_i, s)
+            if rec is not None:
+                rec.end("lm.admit", span, step=self._step, rid=s.rid,
+                        pages=len(pages))
 
     def _prefill(self, slot_i: int, s: _Slot) -> None:
         """Stream the prompt into this slot's pages in fixed-size chunks
-        (batch 1); the final chunk's logits yield the first token."""
+        (batch 1); the final chunk's logits yield the first token, stamped
+        when they reach the host."""
         c, s_p = self.chunk, len(s.tokens)
         table_row = self._table[slot_i: slot_i + 1]
-        logits = None
+        rec = self.spans
+        logits = t_host = None
         for c0 in range(0, s_p, c):
             if self.faults is not None:
                 ev = self.faults.poll("prefill", dt=1.0)
                 if ev is not None:
                     self.faults.raise_for(ev)
+            if rec is not None:
+                span = rec.begin()
             piece = s.tokens[c0: c0 + c]
             buf = np.zeros((1, c), np.int32)
             buf[0, : len(piece)] = piece
@@ -798,6 +920,11 @@ class ContinuousLMEngine(_SubmitRetryMixin):
                                     np.asarray([c0], np.int32),
                                     np.asarray([len(piece)], np.int32))
             self.stats["prefill_chunks"] += 1
+            if rec is not None:
+                t_host = rec.end("lm.prefill_chunk", span, step=self._step,
+                                 rows=1, seq=c, q=[len(piece)],
+                                 ctx=[c0 + len(piece)])
+        s.times.append(self.clock() if t_host is None else t_host)
         s.pos = s_p
         first = int(np.argmax(logits[0, (s_p - 1) % c]))
         s.emitted = [first]
@@ -807,8 +934,9 @@ class ContinuousLMEngine(_SubmitRetryMixin):
 
     def _decode_step(self) -> None:
         """One step of the persistent in-flight batch: every active slot
-        inserts its last token and emits the next; finished slots retire
-        and free their pages mid-flight."""
+        inserts its last token and emits the next, all stamped with one
+        clock read; finished slots retire and free their pages
+        mid-flight."""
         active = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         if not active:
             return
@@ -816,6 +944,9 @@ class ContinuousLMEngine(_SubmitRetryMixin):
             ev = self.faults.poll("decode", dt=1.0)
             if ev is not None:
                 self.faults.raise_for(ev)
+        rec = self.spans
+        if rec is not None:
+            span = rec.begin()
         toks = np.zeros((self.num_slots, 1), np.int32)
         pos = np.zeros((self.num_slots,), np.int32)
         valid = np.zeros((self.num_slots,), np.int32)
@@ -824,12 +955,19 @@ class ContinuousLMEngine(_SubmitRetryMixin):
             pos[i] = s.pos
             valid[i] = 1
         logits = self._dispatch(self._table, toks, pos, valid)
+        if rec is None:
+            t_host = self.clock()
+        else:
+            t_host = rec.end("lm.decode_step", span, step=self._step,
+                             rows=self.num_slots, seq=1, q=[1] * len(active),
+                             ctx=[int(pos[i]) + 1 for i, _ in active])
         self._step += 1
         self.stats["steps"] += 1
         self.stats["padded_rows"] += self.num_slots - len(active)
         for i, s in active:
             nxt = int(np.argmax(logits[i, 0]))
             s.emitted.append(nxt)
+            s.times.append(t_host)
             s.last_tok = nxt
             s.pos += 1
             if len(s.emitted) >= s.new_tokens:
@@ -842,7 +980,8 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         self._table[slot_i, :] = self.pool.null_page
         self._results[s.rid] = Result(
             s.rid, np.asarray(s.emitted[: s.new_tokens], np.int32),
-            s.t_submit, self.clock(), 1, 1, t_start=s.t_start)
+            s.t_submit, self.clock(), 1, 1, t_start=s.t_start,
+            token_times=tuple(s.times[: s.new_tokens]))
         self.stats["retirements"] += 1
         self.stats["requests"] += 1
 
@@ -911,6 +1050,9 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         if (self._last_commit is not None
                 and self._step - self._last_commit < self.epoch_steps):
             return
+        rec = self.spans
+        if rec is not None:
+            span = rec.begin()
         extra = dict(
             step=self._step, next_rid=self._next_rid,
             plan_fp=str(self._plan_fp), table=self._table.tolist(),
@@ -919,20 +1061,24 @@ class ContinuousLMEngine(_SubmitRetryMixin):
                 rid=s.rid, t_submit=s.t_submit, t_start=s.t_start,
                 tokens=[int(t) for t in s.tokens], new_tokens=s.new_tokens,
                 pages=[int(p) for p in s.pages], pos=s.pos,
-                emitted=list(s.emitted), last_tok=s.last_tok)
+                emitted=list(s.emitted), last_tok=s.last_tok,
+                times=list(s.times))
                 for s in self._slots],
             waiting=[dict(rid=p.rid, tokens=[int(t) for t in p.tokens],
                           new_tokens=p.new_tokens, t_submit=p.t_submit)
                      for p in self._waiting],
             results={str(r.rid): dict(
                 value=[int(v) for v in r.value], t_submit=r.t_submit,
-                t_done=r.t_done, t_start=r.t_start)
+                t_done=r.t_done, t_start=r.t_start,
+                times=list(r.token_times))
                 for r in self._results.values()},
             dead=list(self.dead_letters),
         )
         self.ckpt.save(self._step, self._pools, extra=extra, tag="cbe")
         self._last_commit = self._step
         self.stats["commits"] += 1
+        if rec is not None:
+            rec.end("lm.commit", span, step=self._step)
 
     def _try_restore(self) -> bool:
         step = self.ckpt.latest_step(tag="cbe")
@@ -950,7 +1096,7 @@ class ContinuousLMEngine(_SubmitRetryMixin):
                 d["rid"], d["t_submit"], d["t_start"],
                 np.asarray(d["tokens"], np.int32), d["new_tokens"],
                 list(d["pages"]), d["pos"], list(d["emitted"]),
-                d["last_tok"])
+                d["last_tok"], list(d.get("times", [])))
             for d in extra["slots"]]
         self._waiting = deque(
             _Pending(d["rid"], np.asarray(d["tokens"], np.int32),
@@ -959,7 +1105,8 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         self._results = {
             int(rid): Result(int(rid), np.asarray(d["value"], np.int32),
                              d["t_submit"], d["t_done"], 1, 1,
-                             t_start=d["t_start"])
+                             t_start=d["t_start"],
+                             token_times=tuple(d.get("times", ())))
             for rid, d in extra["results"].items()}
         self.dead_letters = list(extra["dead"])
         self._step = int(extra["step"])
@@ -987,6 +1134,8 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         self.pool = PagePool(self.pool.num_pages, self.page_size)
         self._step = 0
         self._last_commit = None
+        if self.spans is not None:
+            self.spans.abandon()
         if self.ckpt is not None:
             self._try_restore()
 

@@ -7,8 +7,9 @@ prefetch misuse.  These tests lower and compile each kernel at the widths
 the serve paths run (AlexNet at 224 and 227, the SVHN CNN at 40,
 smollm-360m's projections, the binary AND+popcount GEMM, flash prefill
 and paged decode) for a
-described v5e chip.  Nothing runs, so they say nothing about results or
-speed.
+described v5e chip, and check that every kernel's ``name`` reaches its
+lowered call, where a device trace finds it.  Nothing runs, so they say
+nothing about results or speed.
 
 The topology is described inside a module fixture (never at import time):
 only one process may load the TPU library, and every test worker imports
@@ -26,8 +27,11 @@ from repro.core.prequant import level_dtype
 from repro.core.quant import W1A4
 from repro.kernels import ops
 from repro.kernels.attn_flash import attn_flash_pallas, attn_paged_pallas
+from repro.kernels.bitgemm import bitgemm_packed_pallas
+from repro.kernels.bitgemm_mxu import int8_matmul_pallas
 from repro.kernels.conv_implicit import conv_implicit_pallas
 from repro.kernels.fused_qgemm import fused_qgemm_pallas
+from repro.kernels.quantpack import quantize_pack_pallas
 from repro.models.cnn import alexnet_spec, svhn_cnn_spec
 
 
@@ -158,3 +162,50 @@ def test_attn_paged_compiles_at_smollm_serve(one_chip, batch, seq):
                     pool, pool, ((n_pages + 1, ps), jnp.int32),
                     ((batch, table), jnp.int32), ((batch, seq), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+# Each kernel's ``pallas_call`` name, a function that calls it, and its
+# operands: the name reaches the lowered Mosaic call, so a device trace
+# names the kernel's op (``conv_implicit.3``, not its caller's).
+KERNEL_NAMES = {
+    "conv_implicit": (
+        lambda x, w, s, z: conv_implicit_pallas(x, w, s, z, kh=3, kw=3,
+                                                a_bits=4, w_bits=1),
+        [((1, 16, 16, 128), jnp.int8), ((9 * 128, 128), jnp.int8),
+         ((), jnp.float32), ((), jnp.float32)]),
+    "fused_qgemm": (
+        lambda a, w, s, z: fused_qgemm_pallas(a, w, s, z, a_bits=4, w_bits=1,
+                                              a_is_levels=True),
+        [((8, 512), jnp.int8), ((512, 256), jnp.int8), ((), jnp.float32),
+         ((), jnp.float32)]),
+    "attn_flash": (
+        lambda q, k, v: attn_flash_pallas(q, k, v, q_bits=8, k_bits=8),
+        [((1, 256, 2, 64), jnp.bfloat16)] * 3),
+    "attn_paged": (
+        lambda q, pk, pv, ppos, tbl, qpos: attn_paged_pallas(
+            q, pk, pv, ppos, tbl, qpos, bits=8, n_q_heads=2),
+        [((2, 1, 2, 64), jnp.bfloat16), ((9, 16, 1, 64), jnp.bfloat16),
+         ((9, 16, 1, 64), jnp.bfloat16), ((9, 16), jnp.int32),
+         ((2, 4), jnp.int32), ((2, 1), jnp.int32)]),
+    "bitgemm": (
+        lambda a, w: bitgemm_packed_pallas(a, w, a_bits=2, w_bits=1),
+        [((2, 8, 128), jnp.uint32), ((1, 128, 128), jnp.uint32)]),
+    "bitgemm_mxu": (int8_matmul_pallas,
+                    [((128, 256), jnp.int8), ((256, 128), jnp.int8)]),
+    "quantpack": (lambda a: quantize_pack_pallas(a, bits=4),
+                  [((128, 256), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_name_in_lowered_text(one_chip, name):
+    """``quantpack`` does not lower for the TPU (Mosaic has no reductions
+    over unsigned integers, and no plan routes to it): its name is checked
+    on the traced call instead."""
+    fn, shapes = KERNEL_NAMES[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    if name == "quantpack":
+        assert f"name={name}\n" in str(jax.make_jaxpr(fn)(*args))
+    else:
+        text = jax.jit(fn).lower(*args).as_text()
+        assert f'kernel_name = "{name}"' in text
