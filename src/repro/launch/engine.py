@@ -15,7 +15,9 @@ multi-request, multi-device system (DESIGN.md §7):
     bucket *i+1*'s arrays transfer and bucket *i-1*'s results harvest; at
     most two buckets are in flight on device (bounded memory; the rest of
     the backpressure story is ``max_pending`` on the queue, see
-    :meth:`ServeEngine.submit`).
+    :meth:`ServeEngine.submit`).  A batch larger than
+    ``_PUT_CHUNK_BYTES`` transfers as concurrent row chunks, joined on
+    the device.
   * **Data-parallel execution** — with more than one device, the batched
     forward runs under ``shard_map`` over the mesh's ``data`` axis
     (:func:`repro.distributed.sharding.data_parallel`): params replicated,
@@ -330,6 +332,21 @@ def _pow2_ceil(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
 
 
+# One ``jax.device_put`` of one array moves about 2.7 GB/s to a TPU v5e;
+# the same 19.3 MB as eight 2.4 MB row slices in one call, about 6.8 GB/s
+# (PERF.md §5): the host copies of the slices run at once.
+_PUT_CHUNK_BYTES = 4 << 20
+
+
+def _put_chunks(nbytes: int, rows: int) -> int:
+    """Row chunks to stage a batch in: the smallest power of two whose
+    chunks hold at most ``_PUT_CHUNK_BYTES`` each, capped at ``rows``."""
+    k = 1
+    while k < rows and nbytes > k * _PUT_CHUNK_BYTES:
+        k *= 2
+    return min(k, rows)
+
+
 def _seeded_rng(retry_rng) -> np.random.RandomState:
     """Normalize the injectable backoff RNG: None -> seed 0, int -> that
     seed, a RandomState -> used as-is.  Injection makes retry jitter a
@@ -407,7 +424,10 @@ class ServeEngine(_SubmitRetryMixin):
             self._params = jax.device_put(runner.params, replicated(mesh))
         else:
             self._params = jax.device_put(runner.params)
-        self.stats = dict(dispatches=0, requests=0, padded_rows=0)
+        # put_chunks: host->device transfers issued (one per bucket unless
+        # a bucket is staged in row chunks)
+        self.stats = dict(dispatches=0, requests=0, padded_rows=0,
+                          put_chunks=0)
         self.spans: SpanRecorder | None = None
 
     def record_spans(self) -> SpanRecorder:
@@ -517,13 +537,22 @@ class ServeEngine(_SubmitRetryMixin):
                 from repro.distributed.sharding import data_parallel
                 fn = jax.jit(data_parallel(fwd, self.mesh))
             else:
-                fn = jax.jit(fwd)
+                def staged(params, x, fwd=fwd):
+                    # a batch staged in row chunks (_stage) is joined on
+                    # the device: the same rows, so the same logits
+                    if isinstance(x, tuple):
+                        x = jnp.concatenate(x)
+                    return fwd(params, x)
+
+                fn = jax.jit(staged)
             self._fns[cache_key] = fn
         return self._fns[cache_key]
 
     def _stage(self, bucket: Bucket):
-        """Start the host->device transfer for one bucket (async).  The
-        bucket's id is its ``serve.stage`` span's (None, recorder off)."""
+        """Start the host->device transfer for one bucket (async): one put,
+        or on one device ``_put_chunks`` row slices of the collated batch
+        in one call, which the program joins.  The bucket's id is its
+        ``serve.stage`` span's (None, recorder off)."""
         rec, bid = self.spans, None
         if rec is not None:
             stage = rec.begin()
@@ -539,11 +568,15 @@ class ServeEngine(_SubmitRetryMixin):
             put = rec.begin(t)
         if self.mesh is not None:
             from repro.distributed.sharding import batch_sharding
-            dev = jax.device_put(batch, batch_sharding(self.mesh))
+            k, dev = 1, jax.device_put(batch, batch_sharding(self.mesh))
         else:
-            dev = jax.device_put(batch)
+            k = _put_chunks(batch.nbytes, padded)
+            dev = (jax.device_put(batch) if k == 1
+                   else tuple(jax.device_put(np.array_split(batch, k))))
+        self.stats["put_chunks"] += k
         if rec is not None:
-            t = rec.end("serve.put", put, bucket=bid, bytes=int(batch.nbytes))
+            t = rec.end("serve.put", put, bucket=bid, bytes=int(batch.nbytes),
+                        chunks=k)
             rec.end("serve.stage", stage, t, bucket=bid)
         return bucket, padded, dev, bid
 

@@ -413,6 +413,7 @@ class ResilientServeEngine(ServeEngine):
                 batch = staged
             else:
                 FaultPlan.raise_for(ev)
+        self.stats["put_chunks"] += 1
         return jax.device_put(batch)
 
     # -- epoch decode with micro-checkpoints --------------------------------

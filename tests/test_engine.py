@@ -19,6 +19,7 @@ import pytest
 
 from repro.configs import SINGLE, all_configs
 from repro.core.quant import PAPER_CONFIGS, W1A4
+from repro.launch import engine as engine_mod
 from repro.launch.engine import (BucketBatcher, CNNRunner, LMRunner, QueueFull,
                                  Request, ServeEngine, run_offered_load)
 from repro.models import transformer as T
@@ -153,6 +154,80 @@ def test_cnn_mixed_shape_buckets():
     np.testing.assert_array_equal(res[3].value, ref12[1])
 
 
+# Row-chunked staging: ``_PUT_CHUNK_BYTES`` set small so that SVHN-sized
+# buckets (3,072 B an image) go up in chunks, as a 224x224 AlexNet bucket
+# does at the real threshold.
+ROW = IMGS[0].nbytes
+MORE_IMGS = IMGS + [np.random.RandomState(10 + i).uniform(size=(16, 16, 3))
+                    .astype(np.float32) for i in range(4)]
+
+
+@pytest.mark.parametrize("n,max_batch,chunk_bytes,chunks", [
+    (8, 8, 2 * ROW, [4]),            # full bucket: 8 rows in 4 chunks
+    (5, 8, 2 * ROW, [4]),            # 5 requests padded to 8 rows
+    (6, 6, 5000, [4]),               # 6 rows in 4 uneven chunks (2,2,1,1)
+    (3, 3, 1, [3]),                  # capped at the bucket's rows
+    (10, 8, 3 * ROW, [4, 1]),        # a full bucket, then 2 rows in one
+])
+def test_cnn_chunked_staging_bit_identical(monkeypatch, n, max_batch,
+                                           chunk_bytes, chunks):
+    """A bucket staged as row chunks and joined on the device serves the
+    same logits, bit for bit, as the same bucket in one put."""
+    imgs = MORE_IMGS[:n]
+    one = _cnn_engine(W1A4, max_batch)
+    ref = one.serve(imgs)
+    assert one.stats["put_chunks"] == one.stats["dispatches"]
+    monkeypatch.setattr(engine_mod, "_PUT_CHUNK_BYTES", chunk_bytes)
+    eng = _cnn_engine(W1A4, max_batch)
+    res = eng.serve(imgs)
+    assert eng.stats["put_chunks"] == sum(chunks)
+    assert eng.stats["dispatches"] == len(chunks)
+    for a, b in zip(res, ref):
+        assert (a.batch, a.padded) == (b.batch, b.padded)
+        np.testing.assert_array_equal(a.value, b.value)
+
+
+def test_cnn_chunked_staging_one_program_per_padded_batch(monkeypatch):
+    """The chunk count follows the padded batch, so a full and a padded
+    bucket of 8 rows share one program, compiled once."""
+    monkeypatch.setattr(engine_mod, "_PUT_CHUNK_BYTES", 2 * ROW)
+    eng = _cnn_engine(W1A4, 8)
+    eng.serve(MORE_IMGS[:8])
+    eng.serve(MORE_IMGS[:5])
+    eng.serve(MORE_IMGS[:1])
+    assert eng.stats["put_chunks"] == 4 + 4 + 1
+    assert sorted(padded for (_, padded, _) in eng._fns) == [1, 8]
+    assert all(fn._cache_size() == 1 for fn in eng._fns.values())
+
+
+@pytest.mark.parametrize("kind", ["cnn", "lm", "resilient"])
+def test_small_and_checked_buckets_stage_in_one_put(monkeypatch, kind):
+    """A sub-threshold CNN bucket, an LM bucket (int32 tokens, kilobytes)
+    and ``ResilientServeEngine``'s checked staging each issue exactly one
+    put per bucket and serve what the plain engine serves."""
+    if kind == "lm":
+        cfg, params = _lm_setup()
+        prompts = [np.random.RandomState(i).randint(0, cfg.vocab, size=(8,))
+                   .astype(np.int32) for i in range(5)]
+        eng = ServeEngine(LMRunner(params, cfg, new_tokens=3), max_batch=4)
+        eng.serve(prompts)
+        assert eng.stats["put_chunks"] == eng.stats["dispatches"] == 2
+        return
+    ref = _cnn_engine(W1A4, 4).serve(IMGS)
+    if kind == "cnn":
+        eng = _cnn_engine(W1A4, 4)
+    else:
+        from repro.resilience import ResilientServeEngine
+
+        monkeypatch.setattr(engine_mod, "_PUT_CHUNK_BYTES", 1)
+        eng = ResilientServeEngine(CNNRunner(SERVE_PARAMS, SPEC, W1A4),
+                                   max_batch=4)
+    res = eng.serve(IMGS)
+    assert eng.stats["put_chunks"] == eng.stats["dispatches"] == 2
+    for a, b in zip(res, ref):
+        np.testing.assert_array_equal(a.value, b.value)
+
+
 def test_engine_single_device_fallback_and_stats():
     """On one device the engine must take the plain-jit path (mesh None)."""
     from repro.launch.mesh import make_serve_mesh
@@ -162,7 +237,8 @@ def test_engine_single_device_fallback_and_stats():
     eng = _cnn_engine(W1A4, 4)
     assert eng.mesh is None or eng._n_data == len(jax.devices())
     res = eng.serve(IMGS[:4])
-    assert eng.stats == dict(dispatches=1, requests=4, padded_rows=0)
+    assert eng.stats == dict(dispatches=1, requests=4, padded_rows=0,
+                             put_chunks=1)
     assert all(r.latency_s >= 0 for r in res)
 
 
@@ -455,10 +531,14 @@ imgs = [np.random.RandomState(i).uniform(size=(16, 16, 3)).astype(np.float32)
         for i in range(19)]  # ragged: 16 + 3
 mesh = make_serve_mesh()
 assert mesh is not None and mesh.devices.size == 8, mesh
+# the mesh path keeps one sharded put per bucket, whatever the chunk size
+import repro.launch.engine as E
+E._PUT_CHUNK_BYTES = 1
 runner = CNNRunner(sp, spec, W1A4)
 eng = ServeEngine(runner, max_batch=16, mesh=mesh)
 res = eng.serve(imgs)
 assert eng.stats["dispatches"] == 2, eng.stats
+assert eng.stats["put_chunks"] == 2, eng.stats
 # ragged tail (3) padded up to the device count
 assert res[-1].padded % 8 == 0 and res[-1].batch == 3, res[-1]
 # 1) engine plumbing is exact: a direct shard_map call on the same padded

@@ -82,7 +82,8 @@ def test_recorder_off_records_nothing(make):
     eng.serve(payloads)
     assert eng.spans is None
     if make is _cnn_engine:
-        assert eng.stats == dict(dispatches=2, requests=5, padded_rows=0)
+        assert eng.stats == dict(dispatches=2, requests=5, padded_rows=0,
+                                 put_chunks=2)
     else:
         assert eng.stats["requests"] == 3 and eng.stats["dispatches"] == (
             eng.stats["prefill_chunks"] + eng.stats["steps"])
@@ -134,10 +135,34 @@ def test_cnn_spans_per_bucket():
     row = IMGS[0].nbytes
     assert [r["bytes"] for r in records["serve.put"]] == [
         r["padded"] * row for r in collate]
+    assert [r["chunks"] for r in records["serve.put"]] == [1] * 4
     built = [(r["padded"], d["built"])
              for r, d in zip(collate, records["serve.dispatch"])]
     assert built == [(4, True), (1, True), (4, False), (1, False)]
-    assert eng.stats == dict(dispatches=4, requests=10, padded_rows=0)
+    assert eng.stats == dict(dispatches=4, requests=10, padded_rows=0,
+                             put_chunks=4)
+
+
+def test_cnn_put_span_counts_chunks(monkeypatch):
+    """A bucket staged in row chunks: ``serve.put`` carries ``chunks`` and
+    still the bucket's whole ``bytes``; ``serve.collate`` stays one span
+    per bucket with its ``batch``/``padded``."""
+    from repro.launch import engine
+
+    row = IMGS[0].nbytes
+    monkeypatch.setattr(engine, "_PUT_CHUNK_BYTES", row)
+    eng = _cnn_engine(clock=_fake_clock())
+    rec = eng.record_spans()
+    eng.serve(IMGS[:3])               # one bucket: 3 rows padded to 4
+    eng.serve(IMGS)                   # 4 rows, then 1
+    collate, put = rec.records["serve.collate"], rec.records["serve.put"]
+    assert [(r["batch"], r["padded"]) for r in collate] == [
+        (3, 4), (4, 4), (1, 1)]
+    assert [(r["chunks"], r["bytes"]) for r in put] == [
+        (4, 4 * row), (4, 4 * row), (1, row)]
+    assert [r["bucket"] for r in put] == [r["bucket"] for r in collate]
+    assert eng.stats["put_chunks"] == 9
+    _assert_nested(rec.records)
 
 
 @pytest.mark.parametrize("make", [_cnn_engine, _lm_engine])
