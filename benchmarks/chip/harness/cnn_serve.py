@@ -11,7 +11,8 @@ the ``Deployment``'s ``submit``/``pump``/``drain`` with the traffic:
   deadline is honoured.
 
 Host spans: ``collate`` wraps the runner's public ``collate`` (the host
-stacking the device waits on), with the padded batch of each bucket.
+stacking the device waits on), with the padded batch of each bucket; in
+a traced run the engine's own ``serve.*`` spans join them.
 """
 from __future__ import annotations
 
@@ -19,7 +20,7 @@ import time
 
 import numpy as np
 
-from .common import Run, seed32, span, timed
+from .common import Run, record_engine_spans, seed32, span, timed
 from .traffic import images, open_schedule
 
 
@@ -97,10 +98,12 @@ class System:
 
     def drive(self, t_start: float, t_end: float, tracer) -> None:
         """Traffic from ``t_start``; the window is ``[run.t0, t_end]``."""
+        merge = record_engine_spans(self.run, self.dep, tracer)
         if self.traffic["loop"] == "closed":
             self._closed(t_end, tracer)
         else:
             self._open(t_start, t_end, tracer)
+        merge()
 
     def _closed(self, t_end: float, tracer) -> None:
         clients = int(self.traffic["clients"])

@@ -199,6 +199,20 @@ class Run:
                                                     **info))
 
 
+def record_engine_spans(run: Run, engine, tracer):
+    """In a traced run, turn on the engine's own span recorder
+    (``record_spans()``); returns a function that merges what it recorded
+    into ``run.spans``, where the trace's host spans come from."""
+    rec = engine.record_spans() if tracer.enabled else None
+
+    def merge() -> None:
+        if rec is not None:
+            for name, recs in rec.records.items():
+                run.spans.setdefault(name, []).extend(recs)
+
+    return merge
+
+
 def timed(run: Run, name: str, fn: Callable, info: Callable | None = None):
     """Wrap ``fn`` so each call lands in ``run.spans[name]`` (host clock).
     Each call first lets the tracer start on time, however long the call

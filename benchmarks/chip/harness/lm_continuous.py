@@ -20,6 +20,10 @@ code is unchanged):
   ``(slots, 1)`` call, with the live context of every row;
 * ``_prefill``: the admitted slot's first token, stamped on return;
 * ``_decode_step``: one token for every live slot, stamped on return.
+
+The ``_dispatch`` wrapper also starts the profiler on time inside a
+``pump()`` that runs tens of prefill chunks.  In a traced run the
+engine's own ``lm.*`` spans join the harness's.
 """
 from __future__ import annotations
 
@@ -28,7 +32,7 @@ import time
 
 import numpy as np
 
-from .common import Run, seed32, span, timed
+from .common import Run, record_engine_spans, seed32, span, timed
 from .traffic import BLOCK, lm_requests, open_schedule
 
 
@@ -170,10 +174,12 @@ class System:
         self.reqs[rid] = _Req(rid, t_arrive, prompt, n_out)
 
     def drive(self, t_start: float, t_end: float, tracer) -> None:
+        merge = record_engine_spans(self.run, self.engine, tracer)
         if self.traffic["loop"] == "closed":
             self._closed(t_end, tracer)
         else:
             self._open(t_start, t_end, tracer)
+        merge()
         for r in self.reqs.values():
             self.run.requests.append(dict(
                 rid=r.rid, t_arrive=r.t_arrive, t_admit=r.t_admit,

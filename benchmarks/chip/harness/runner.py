@@ -134,6 +134,8 @@ def _run(args, t_process, manifest, require_tpu, peaks, fault) -> dict:
         data["host"] = host_spans(run.spans, tracer.t_start, data["window_ns"])
         run.trace = Trace(data)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        log(f"bench: traced window {run.trace.window_s:.6f} s on the "
+            f"device, {tracer.t_stop - tracer.t_start:.6f} s on the host")
         # the profiler slows the host; host readers take the untraced part
         run.t1 = tracer.t_start
     run.find = m.find

@@ -6,9 +6,12 @@ window nothing.  It starts between two calls into the system, or inside
 one (the drivers poll it from their wrappers).  Only the device is
 traced: host tracing slows the host path it would observe (the H2D
 relayout of an image batch) about tenfold.  A tiny marker program runs
-on the device right after the start and right before the stop; its two
-executions bound the traced window on the device's clock, and map the
-harness's own host spans (``run.spans``) onto it.
+on the device right after the start and right before the stop, and the
+host waits for each: the two executions' ends bound the traced window on
+the device's clock, and the host's clock read as each wait returns
+bounds it on the host's, so that both ends are taken alike (the stop's
+marker runs after whatever the device still had queued).  The pair maps
+the harness's own host spans (``run.spans``) onto the device's clock.
 
 ``load`` reads the ``.xplane.pb`` (``jax.profiler.ProfileData``) into a
 plain dict, which is also the form of the small recorded trace the
@@ -73,14 +76,14 @@ class Tracer:
             return
         import jax
 
-        self.t_stop = time.perf_counter()
         self._mark()
+        self.t_stop = time.perf_counter()
         jax.profiler.stop_trace()
 
 
 def load(trace_dir: str) -> dict:
-    """The device ops of the traced window, bounded by the two marker
-    executions (the first one's end, the last one's start)."""
+    """The device ops of the traced window, bounded by the ends of the
+    first and the last marker executions."""
     from jax.profiler import ProfileData
 
     files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -103,7 +106,7 @@ def load(trace_dir: str) -> dict:
     if len(marks) < 2:
         raise ValueError(f"trace holds {len(marks)} window marks, not 2")
     marks.sort()
-    return dict(window_ns=[marks[0][1], marks[-1][0]], ops=ops, host=[])
+    return dict(window_ns=[marks[0][1], marks[-1][1]], ops=ops, host=[])
 
 
 def host_spans(spans: dict, t_start: float, window_ns) -> list:

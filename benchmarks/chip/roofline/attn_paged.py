@@ -14,13 +14,7 @@ from __future__ import annotations
 
 import re
 
-# The kernel carries no name of its own in the trace (its op is a
-# ``closed_call``); it is the Mosaic call whose operands open with the
-# scalar-prefetched page table (B, P), the per-slot scales (B,) and the
-# two zero points.
-PATTERN = re.compile(r'custom_call_target="tpu_custom_call", '
-                     r"operand_layout_constraints=\{s32\[\d+,\d+\]\{1,0\}, "
-                     r"f32\[\d+\]\{0\}, s32\[2\]\{0\}")
+PATTERN = re.compile(r"attn_paged")
 
 
 def least_time(q_rows: list, ctx: list, model: dict, peaks: dict) -> float:
@@ -34,4 +28,7 @@ def least_time(q_rows: list, ctx: list, model: dict, peaks: dict) -> float:
 
 
 def match(name: str) -> bool:
-    return bool(PATTERN.search(name))
+    """The kernel's own ops: the op's name (the HLO text before " = "),
+    which is the ``pallas_call``'s name, not ops that merely take its
+    output."""
+    return bool(PATTERN.match(name.split(" = ", 1)[0].lstrip("%")))

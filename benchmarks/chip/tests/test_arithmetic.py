@@ -1,4 +1,3 @@
-import importlib.util
 import os
 import statistics
 
@@ -70,14 +69,3 @@ def test_images_per_s_and_image_tail():
     assert reader("image_p99_ms").read(run) == pytest.approx(
         1e3 * np.percentile(lat, 99))
 
-
-def test_every_manifest_metric_has_a_reader():
-    import json
-
-    with open(os.path.join(os.path.dirname(os.path.dirname(BENCH_DIR)),
-                           "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        path = os.path.join(BENCH_DIR, "metrics", m["name"] + ".py")
-        assert importlib.util.spec_from_file_location("m", path) is not None
-        assert hasattr(load_module(path), "read"), m["name"]

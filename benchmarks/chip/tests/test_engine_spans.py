@@ -127,3 +127,29 @@ def test_overlap_splits_what_the_midpoint_rule_gives_collate(trace):
     wait = _read("idle_in_wait.cnn", run)
     assert stage > idle_in(run, "collate") and wait > 10.0
     assert stage + wait > 0.7 * _read("idle_share.cnn", run)
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_drivers_merge_engine_spans_only_in_traced_runs(traced):
+    """``record_engine_spans`` turns the engine's recorder on only when
+    the run is traced, and merges its records beside the harness's."""
+    from types import SimpleNamespace
+
+    from harness.common import record_engine_spans
+
+    rec = SimpleNamespace(records={})
+    calls = []
+
+    def record_spans():
+        calls.append(1)
+        return rec
+
+    run = Run(cell={}, config={}, traffic={},
+              spans={"collate": [dict(t=0.1, dt=0.002, batch=32)]})
+    merge = record_engine_spans(run, SimpleNamespace(record_spans=record_spans),
+                                SimpleNamespace(enabled=traced))
+    rec.records["serve.put"] = [dict(t=0.2, dt=0.001, id=0, parent=None)]
+    merge()
+    assert calls == ([1] if traced else [])
+    assert ("serve.put" in run.spans) is traced
+    assert len(run.spans["collate"]) == 1

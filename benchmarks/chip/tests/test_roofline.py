@@ -62,6 +62,25 @@ def test_attn_paged_counts_live_context():
     assert t == pytest.approx(max(qk / 393e12 + qk / 197e12, nbytes / 819e9))
 
 
+def test_attn_paged_found_by_name_as_by_its_operands():
+    """The kernel's ``pallas_call`` name finds the calls and the device
+    time that its operand layouts (the scalar-prefetched page table, the
+    per-slot scales, the two zero points) found before it was named."""
+    import re
+
+    from harness.trace import Trace
+
+    operands = re.compile(r'custom_call_target="tpu_custom_call", '
+                          r"operand_layout_constraints=\{s32\[\d+,\d+\]\{1,0\}, "
+                          r"f32\[\d+\]\{0\}, s32\[2\]\{0\}")
+    with open(os.path.join(BENCH_DIR, "tests", "data",
+                           "smollm_chat_offline_trace.json")) as f:
+        t = Trace(json.load(f))
+    by_name = t.matching(roof("attn_paged").match)
+    assert by_name[0] == 64
+    assert by_name == t.matching(lambda name: operands.search(name))
+
+
 def test_peaks_table_has_a_source_and_refuses_unknown_devices():
     from harness.common import BenchError, load_peaks
 

@@ -4,9 +4,12 @@
 * ``alexnet_batch_trace.json``: the first 117 ms of a traced
   ``alexnet-w1a4.batch`` window, six closed-loop rounds of two 32-image
   buckets, with the harness's host spans;
-* ``smollm_chat_trace.json``: 42 ms of SmolLM-360M decode under a chat
-  mix (16 slots), four calls of the paged attention kernel inside the
-  layer loop."""
+* ``smollm_chat_offline_trace.json``: 403 ms of a traced
+  ``smollm-360m-w1a8.chat-offline`` window (one v5e, seed 2718281829):
+  one batch-1 prefill chunk of a replacement, then one decode step of 16
+  live slots, with the harness's and the engine's host spans, and under
+  ``spans`` the harness's step records (``t`` in seconds from the
+  trace's start)."""
 import json
 import os
 
@@ -17,6 +20,7 @@ from harness.trace import Trace, _union
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 DATA = os.path.join(HERE, "data", "alexnet_batch_trace.json")
+LM_TRACE = "smollm_chat_offline_trace.json"
 PEAKS = {"bf16_flops": 197e12, "int8_ops": 393e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -62,15 +66,16 @@ def test_breakdown_names_ops_and_gaps(trace):
     assert b["idle_gaps"][0][0] == "collate"
 
 
-def test_paged_kernel_found_by_its_operands():
-    with open(os.path.join(HERE, "data", "smollm_chat_trace.json")) as f:
+def test_paged_kernel_found_by_its_name():
+    with open(os.path.join(HERE, "data", LM_TRACE)) as f:
         t = Trace(json.load(f))
     calls, device_s = t.matching(_kernel("attn_paged").match)
-    assert calls == 4 and device_s > 0.5 * t.window_s
+    # 32 layers of one prefill chunk and one decode step
+    assert calls == 64 and device_s > 0.5 * t.window_s
     assert t.matching(_kernel("conv_implicit").match) == (0, 0.0)
     # the layer loop contains the kernel: it is not listed beside it
     names = [n for n, _ in t.breakdown()["device_ops"]]
-    assert names[0].startswith("closed_call")
+    assert names[0].startswith("attn_paged")
     assert not any(n.startswith("while") for n in names)
 
 
