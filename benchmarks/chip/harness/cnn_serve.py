@@ -10,6 +10,10 @@ the ``Deployment``'s ``submit``/``pump``/``drain`` with the traffic:
   from its scheduled time; ``pump`` runs between arrivals so the flush
   deadline is honoured.
 
+A cell on more than one chip serves through the program's data mesh
+(``repro.launch.mesh.make_serve_mesh``), with buckets of ``max_batch``
+rows a chip.
+
 Host spans: ``collate`` wraps the runner's public ``collate`` (the host
 stacking the device waits on), with the padded batch of each bucket; in
 a traced run the engine's own ``serve.*`` spans join them.
@@ -39,6 +43,7 @@ class System:
 
         from repro import api
         from repro.core.quant import PAPER_CONFIGS
+        from repro.launch.mesh import make_serve_mesh
         from repro.models.cnn import ConvSpec
 
         self.config, self.traffic, self.run = config, traffic, run
@@ -55,9 +60,14 @@ class System:
             target=eng["target"], batch_hints=tuple(eng["batch_hints"]))
         del params
         self.engines = [dict(lp.engines) for lp in compiled.plan.layers]
+        # a cell on n chips serves over an n-device data mesh (none for
+        # one): parameters replicated, each bucket of n * max_batch rows
+        # put sharded, so every chip runs the one-chip bucket
+        chips = int(run.cell["chips"])
         self.dep = compiled.serve(
-            max_batch=int(eng["max_batch"]),
-            flush_deadline_s=float(eng["flush_deadline_ms"]) / 1e3)
+            max_batch=int(eng["max_batch"]) * chips,
+            flush_deadline_s=float(eng["flush_deadline_ms"]) / 1e3,
+            mesh=make_serve_mesh(chips))
         runner = self.dep.engine.runner
         runner.collate = timed(run, "collate", runner.collate,
                                info=lambda payloads, pad_to: dict(batch=pad_to))
@@ -172,7 +182,10 @@ def check(config: dict, seed: int, sample: list, reference,
           control: bool) -> dict:
     """Every sampled answer against the reference on the same image:
     ``logit_err`` is the widest max |served - reference| over a sample,
-    over the reference's RMS logit."""
+    over the reference's RMS logit.  ``distinct_answers`` and
+    ``distinct_logits`` count the different answers the program served
+    and the reference gives for the sample's ``distinct_images``: 1 where
+    the logits do not depend on the image."""
     params = reference.init_params(config, seed32(seed, 1))
     x = np.stack([img for img, _ in sample])
     served = np.stack([v for _, v in sample]).astype(np.float64)
@@ -181,7 +194,10 @@ def check(config: dict, seed: int, sample: list, reference,
     bad = ~np.isfinite(served).all(axis=1)
     err = np.max(np.abs(served - ref), axis=1) / rms
     out = dict(logit_err=float(np.max(np.where(bad, np.inf, err))),
-               compared=len(sample), nonfinite=int(bad.sum()))
+               compared=len(sample), nonfinite=int(bad.sum()),
+               distinct_images=len({img.tobytes() for img, _ in sample}),
+               distinct_answers=len({row.tobytes() for row in served}),
+               distinct_logits=len({row.tobytes() for row in ref}))
     if control:
         ctl = reference.forward(params, x, config, control=True)
         out["control_logit_err"] = float(np.max(

@@ -2,6 +2,7 @@
 the compile cache, the host clock's bookkeeping, and tail arithmetic."""
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import importlib.util
 import json
@@ -211,6 +212,25 @@ def record_engine_spans(run: Run, engine, tracer):
                 run.spans.setdefault(name, []).extend(recs)
 
     return merge
+
+
+def dispatched_batches(run: Run) -> list[int]:
+    """The padded batch of each CNN bucket whose program ran inside the
+    traced window.  Those are the buckets the engine dispatched
+    (``serve.dispatch``, recorded in traced runs) between the window's
+    two marker waits (``run.trace_span``): each device runs its programs
+    in the order they were queued, so they run after the first marker and
+    before the last.  A bucket's batch is that of the harness's
+    ``collate`` that staged it, the last one before its dispatch."""
+    lo, hi = run.trace_span
+    staged = sorted((s["t"], s["batch"]) for s in run.spans.get("collate", []))
+    starts = [t for t, _ in staged]
+    out = []
+    for d in run.spans.get("serve.dispatch", []):
+        i = bisect.bisect_right(starts, d["t"])
+        if lo <= d["t"] <= hi and i:
+            out.append(staged[i - 1][1])
+    return out
 
 
 def timed(run: Run, name: str, fn: Callable, info: Callable | None = None):

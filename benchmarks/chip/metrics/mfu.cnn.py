@@ -1,6 +1,7 @@
-"""The whole CNN step's share of the chip's peak: images answered per
-second in the window times the least time per image at peak (quantized
-layers' ops at the int8 peak, full-precision layers' at the bf16 peak)."""
+"""The whole CNN step's share of the chips' peak: images answered per
+second in the window times the least time per image at one chip's peak
+(quantized layers' ops at the int8 peak, full-precision layers' at the
+bf16 peak), over the cell's number of chips."""
 from harness.common import load_module
 
 
@@ -12,4 +13,4 @@ def read(run):
     t = sum(2.0 * l["macs"] / (run.peaks["bf16_flops"] if l["fp"]
                                else run.peaks["int8_ops"])
             for l in layers.walk(run.config))
-    return 100.0 * n / run.window_s * t
+    return 100.0 * n / run.window_s * t / int(run.cell.get("chips", 1))

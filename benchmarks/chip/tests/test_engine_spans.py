@@ -99,10 +99,12 @@ def test_cnn_put_ms_reads_put_spans_in_the_window(trace):
 
 def test_engine_spans_do_not_reach_collate_readers(trace):
     """``serve.collate`` is the engine's own span over the same call the
-    harness's ``collate`` wraps: the harness's readers count it once."""
-    harness = {"collate": [dict(t=0.05, dt=0.002, batch=32)] * 12}
+    harness's ``collate`` wraps: the readers count each bucket once."""
+    harness = {"collate": [dict(t=0.05, dt=0.002, batch=32)] * 12,
+               "serve.dispatch": [dict(t=0.053, dt=0.001)] * 12}
     both = dict(harness, **{n: [dict(t=0.05, dt=0.004, batch=32, padded=32,
-                                     bucket=0)] * 12 for n in ENGINE_SPANS})
+                                     bucket=0)] * 12 for n in ENGINE_SPANS
+                            if n != "serve.dispatch"})
     for metric in ("cnn_collate_ms", "conv_implicit_roofline",
                    "fused_qgemm_roofline"):
         alone = _read(metric, _run(trace, spans=harness))

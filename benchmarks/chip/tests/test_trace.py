@@ -87,7 +87,8 @@ def test_roofline_and_idle_readers(trace):
     run.find = lambda kind, name, ext: os.path.join(BENCH_DIR, kind,
                                                     name + ext)
     run.trace_span = (0.0, 1.0)
-    run.spans = {"collate": [dict(t=0.5, dt=0.002, batch=32)] * 12}
+    run.spans = {"collate": [dict(t=0.5, dt=0.002, batch=32)] * 12,
+                 "serve.dispatch": [dict(t=0.503, dt=0.001)] * 12}
     run.counters = {"engines": [{32: "fp"}] + [{32: "implicit"}] * 4
                     + [{32: "fused"}] * 2 + [{32: "fp"}]}
     layers = _kernel("cnn_layers").walk(config)
