@@ -474,73 +474,101 @@ def attn_paged_xla(q, pool_k, pool_v, ppos, table, q_pos, *,
     return out.astype(q.dtype)
 
 
-def _paged_kernel(tbl_ref, scal_ref, zint_ref, qpos_ref, q_ref, k_ref,
-                  v_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  bits, causal, window, n_q_heads, n_pages):
-    """One (slot b, table column p) grid step.
+def paged_block_pages(page_size: int, table_pages: int) -> int:
+    """Pages :func:`attn_paged_pallas` takes per grid step: enough for one
+    128-key lane block (8 pages of 16 tokens), at most the whole table.
+    A slot's causal call computes the blocks up to the one holding its
+    last valid query position ``t``: ``t // (page_size * k) + 1`` of
+    them, 0 for a slot with no valid row (``t = -1``)."""
+    return max(1, min(128 // page_size, table_pages))
+
+
+def _paged_kernel(tbl_ref, live_ref, scal_ref, zint_ref, qpos_ref, q_ref,
+                  *refs, bits, causal, window, n_q_heads, n_blocks,
+                  block_pages):
+    """One (slot b, table block j) grid step: ``block_pages`` pages side
+    by side on the key axis.
 
     The KV BlockSpecs are *page-indexed through the scalar-prefetched
-    table* (``tbl[b, p]``), so the kernel sees slot b's p-th page as a
-    contiguous head-major block; the null page arrives fully masked (its
-    ppos is all -1).  Online-softmax (m, l, acc) scratch is carried across
-    the inner page dimension, one (S, 128)/(S, hd) band per query head.
+    table* (one operand per page of the block, ``tbl[b, j*k + i]``), so the
+    kernel sees slot b's pages as contiguous head-major blocks; the null
+    page arrives fully masked (its positions are all -1).  Blocks past the
+    slot's last live one (``live[b]``) repeat that block's indices — no
+    DMA — and skip their compute: every key there lies past the slot's
+    last query, which the causal mask already zeroes, so online softmax
+    would leave (m, l, acc) unchanged bit for bit.  Online-softmax scratch
+    is carried across the block dimension, one (S, 128)/(S, hd) band per
+    query head.
     """
-    b, p = pl.program_id(0), pl.program_id(1)
+    k_refs = refs[:block_pages]
+    v_refs = refs[block_pages: 2 * block_pages]
+    pos_ref, o_ref, m_ref, l_ref, acc_ref = refs[2 * block_pages:]
+    b, j = pl.program_id(0), pl.program_id(1)
     Hp, S, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    Hkv, ps = k_ref.shape[1], k_ref.shape[2]
+    Hkv = k_refs[0].shape[1]
 
-    @pl.when(p == 0)
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    z_q, z_k = zint_ref[0], zint_ref[1]
-    scal = scal_ref[b]
-    pos = pos_ref[0]                      # (1, ps) key positions, -1 dead
-    iq = qpos_ref[0]                      # (S, 1) query positions, -1 dead
-    msk = jnp.broadcast_to(pos >= 0, (S, ps))
-    if causal:
-        msk &= pos <= iq
-    if window is not None:
-        msk &= pos > iq - window
+    @pl.when(j < live_ref[b])
+    def _block():
+        z_q, z_k = zint_ref[0], zint_ref[1]
+        scal = scal_ref[b]
+        pos = pos_ref[0, 0]               # (1, k*ps) key positions, -1 dead
+        iq = qpos_ref[0]                  # (S, 1) query positions, -1 dead
+        kw = pos.shape[1]
+        msk = jnp.broadcast_to(pos >= 0, (S, kw))
+        if causal:
+            msk &= pos <= iq
+        if window is not None:
+            msk &= pos > iq - window
+        # each KV head's block once: levels, nibbles, row sums, values
+        ks, vs = [], []
+        for h in range(Hkv):
+            kl = jnp.concatenate([r[0, h] for r in k_refs], axis=0)
+            ks.append(([(gk.astype(jnp.int8), sk)
+                        for gk, sk in _nibble_split(kl, bits)],
+                       jnp.sum(kl, axis=1)))
+            vs.append(jnp.concatenate(
+                [r[0, h].astype(jnp.float32) for r in v_refs], axis=0))
 
-    g = max(n_q_heads // Hkv, 1)
-    for j in range(Hp):                   # unrolled: Hp is small & static
-        jkv = min(j // g, Hkv - 1)
-        ql = q_ref[0, j].astype(jnp.int32)         # (S, hd) levels
-        kl = k_ref[0, jkv].astype(jnp.int32)       # (ps, hd)
-        acc = jnp.zeros((S, ps), jnp.int32)
-        for gq, sq in _nibble_split(ql, bits):
-            for gk, sk in _nibble_split(kl, bits):
-                d = jax.lax.dot_general(
-                    gq.astype(jnp.int8), gk.astype(jnp.int8),
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.int32)
-                acc += d << (sq + sk)
-        rs_q = jnp.sum(ql, axis=1)
-        rs_k = jnp.sum(kl, axis=1)
-        corr = (acc - z_k * rs_q[:, None] - z_q * rs_k[None, :]
-                + hd * z_q * z_k)
-        logits = jnp.where(msk, corr.astype(jnp.float32) * scal, NEG_INF)
+        g = max(n_q_heads // Hkv, 1)
+        for jq in range(Hp):              # unrolled: Hp is small & static
+            jkv = min(jq // g, Hkv - 1)
+            k_nib, rs_k = ks[jkv]
+            ql = q_ref[0, jq]                          # (S, hd) levels
+            acc = jnp.zeros((S, kw), jnp.int32)
+            for gq, sq in _nibble_split(ql, bits):
+                for gk, sk in k_nib:
+                    d = jax.lax.dot_general(
+                        gq.astype(jnp.int8), gk,
+                        dimension_numbers=(((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.int32)
+                    acc += d << (sq + sk)
+            rs_q = jnp.sum(ql, axis=1)
+            corr = (acc - z_k * rs_q[:, None] - z_q * rs_k[None, :]
+                    + hd * z_q * z_k)
+            logits = jnp.where(msk, corr.astype(jnp.float32) * scal, NEG_INF)
 
-        m_old = m_ref[j, :, :1]
-        m_new = jnp.maximum(m_old, jnp.max(logits, axis=1, keepdims=True))
-        pw = jnp.exp(logits - m_new) * msk
-        cf = jnp.exp(m_old - m_new)
-        l_new = l_ref[j, :, :1] * cf + jnp.sum(pw, axis=1, keepdims=True)
-        acc_ref[j] = acc_ref[j] * cf + jax.lax.dot_general(
-            pw, v_ref[0, jkv].astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[j] = jnp.broadcast_to(m_new, (S, 128))
-        l_ref[j] = jnp.broadcast_to(l_new, (S, 128))
+            m_old = m_ref[jq, :, :1]
+            m_new = jnp.maximum(m_old, jnp.max(logits, axis=1, keepdims=True))
+            pw = jnp.exp(logits - m_new) * msk
+            cf = jnp.exp(m_old - m_new)
+            l_new = l_ref[jq, :, :1] * cf + jnp.sum(pw, axis=1, keepdims=True)
+            acc_ref[jq] = acc_ref[jq] * cf + jax.lax.dot_general(
+                pw, vs[jkv], dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[jq] = jnp.broadcast_to(m_new, (S, 128))
+            l_ref[jq] = jnp.broadcast_to(l_new, (S, 128))
 
-    @pl.when(p == n_pages - 1)
+    @pl.when(j == n_blocks - 1)
     def _epilogue():
-        for j in range(Hp):
-            l = jnp.maximum(l_ref[j, :, :1], 1e-30)
-            o_ref[0, j] = (acc_ref[j] / l).astype(o_ref.dtype)
+        for jq in range(Hp):
+            l = jnp.maximum(l_ref[jq, :, :1], 1e-30)
+            o_ref[0, jq] = (acc_ref[jq] / l).astype(o_ref.dtype)
 
 
 def attn_paged_pallas(q, pool_k, pool_v, ppos, table, q_pos, *,
@@ -550,17 +578,24 @@ def attn_paged_pallas(q, pool_k, pool_v, ppos, table, q_pos, *,
     """Pallas realization (quantized path only; shapes as
     :func:`attn_paged_xla`).
 
-    ``PrefetchScalarGridSpec`` prefetches the page table so the KV
-    BlockSpec index maps can select blocks *through* it — the gather never
-    materializes on the host side of the kernel.  Per-slot scales are a
-    cheap host prepass: s_k is scattered onto the pages through the table
-    (each real page has exactly one owner; the null page's winner is
-    irrelevant — its ppos keeps it fully masked).
+    ``PrefetchScalarGridSpec`` prefetches the page table and each slot's
+    live block count, so the KV BlockSpec index maps select pages
+    *through* the table — the gather never materializes on the host side
+    of the kernel.  The grid is ``(B, ceil(P / k))`` with ``k =
+    paged_block_pages(ps, P)`` pages a step; the table is padded with the
+    null page (index NP) to a whole number of blocks.  A causal call
+    computes a slot's blocks up to the one that holds its last valid query
+    position — the keys past it are all masked, and the engine writes
+    position ``t`` only into column ``t // ps`` — and none for a slot with
+    no valid row; a non-causal call computes the whole table.  Per-slot
+    scales are a cheap host prepass: s_k is scattered onto the pages
+    through the table (each real page has exactly one owner; the null
+    page's winner is irrelevant — its ppos keeps it fully masked).
 
     Every block's last two dims are whole array dims, the TPU tiling rule
     for blocks narrower than (8, 128): queries and pages go head-major
-    (``(.., H, S|ps, hd)``), query positions a column ``(B, S, 1)``, page
-    positions a row ``(NP+1, 1, ps)``.
+    (``(.., H, S|ps, hd)``), query positions a column ``(B, S, 1)``, the
+    key positions of each table block a row ``(B, nblk, 1, k*ps)``.
     """
     B, S, Hp, hd = q.shape
     NP1, ps, Hkv, _ = pool_k.shape
@@ -569,6 +604,8 @@ def attn_paged_pallas(q, pool_k, pool_v, ppos, table, q_pos, *,
         raise ValueError(
             f"paged centered-level dot inexact at head_dim={hd}, bits={bits}")
     z = float(1 << (bits - 1))
+    table = table.astype(jnp.int32)
+    q_pos = q_pos.astype(jnp.int32)
     s_q, s_k = _paged_slot_scales(q, pool_k, ppos, table, bits)
     page_scale = jnp.ones((NP1,), jnp.float32).at[table.reshape(-1)].set(
         jnp.repeat(s_k, P), mode="drop")
@@ -578,41 +615,61 @@ def attn_paged_pallas(q, pool_k, pool_v, ppos, table, q_pos, *,
     scal = (s_q * s_k / math.sqrt(hd)).astype(jnp.float32)       # (B,)
     zint = jnp.asarray([int(z), int(z)], jnp.int32)
 
+    k = paged_block_pages(ps, P)
+    nblk = -(-P // k)
+    tbl = jnp.pad(table, ((0, 0), (0, nblk * k - P)), constant_values=NP1 - 1)
+    if causal:
+        live = jnp.minimum(jnp.max(q_pos, axis=1) // (ps * k) + 1, nblk)
+    else:
+        live = jnp.full((B,), nblk, jnp.int32)
+    kpos = ppos[tbl].reshape(B, nblk, 1, k * ps)
+
     kernel = functools.partial(
         _paged_kernel, bits=bits, causal=causal, window=window,
-        n_q_heads=n_q_heads or Hp, n_pages=P)
-    # index maps take the grid indices first, the prefetched table last
-    page = lambda b, p, tbl: (tbl[b, p], 0, 0, 0)
+        n_q_heads=n_q_heads or Hp, n_blocks=nblk, block_pages=k)
+
+    # index maps take the grid indices first, the prefetched scalars last;
+    # past a slot's last live block they repeat it, so no DMA is issued
+    def block(b, j, live):
+        return jnp.minimum(j, jnp.maximum(live[b] - 1, 0))
+
+    def page(i):
+        return lambda b, j, tbl, live: (tbl[b, block(b, j, live) * k + i],
+                                        0, 0, 0)
+
+    kv_specs = [pl.BlockSpec((1, Hkv, ps, hd), page(i)) for i in range(k)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, P),
+        num_scalar_prefetch=2,
+        grid=(B, nblk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),                # scal (B,)
             pl.BlockSpec(memory_space=pltpu.SMEM),                # zint (2,)
-            pl.BlockSpec((1, S, 1), lambda b, p, tbl: (b, 0, 0)),
-            pl.BlockSpec((1, Hp, S, hd), lambda b, p, tbl: (b, 0, 0, 0)),
-            pl.BlockSpec((1, Hkv, ps, hd), page),
-            pl.BlockSpec((1, Hkv, ps, hd), page),
-            pl.BlockSpec((1, 1, ps), lambda b, p, tbl: (tbl[b, p], 0, 0)),
+            pl.BlockSpec((1, S, 1), lambda b, j, tbl, live: (b, 0, 0)),
+            pl.BlockSpec((1, Hp, S, hd),
+                         lambda b, j, tbl, live: (b, 0, 0, 0)),
+            *kv_specs,
+            *kv_specs,
+            pl.BlockSpec((1, 1, 1, k * ps), lambda b, j, tbl, live: (
+                b, block(b, j, live), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, Hp, S, hd),
-                               lambda b, p, tbl: (b, 0, 0, 0)),
+                               lambda b, j, tbl, live: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hp, S, 128), jnp.float32),
             pltpu.VMEM((Hp, S, 128), jnp.float32),
             pltpu.VMEM((Hp, S, hd), jnp.float32),
         ],
     )
+    kt = kl.transpose(0, 2, 1, 3)
+    vt = pool_v.transpose(0, 2, 1, 3)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hp, S, hd), q.dtype),
         name="attn_paged",
         interpret=interpret,
-    )(table.astype(jnp.int32), scal, zint,
-      q_pos.astype(jnp.int32).reshape(B, S, 1),
-      ql.transpose(0, 2, 1, 3), kl.transpose(0, 2, 1, 3),
-      pool_v.transpose(0, 2, 1, 3), ppos.reshape(NP1, 1, ps))
+    )(tbl, live, scal, zint, q_pos.reshape(B, S, 1),
+      ql.transpose(0, 2, 1, 3), *([kt] * k), *([vt] * k), kpos)
     return out.transpose(0, 2, 1, 3)
 
 

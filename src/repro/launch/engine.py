@@ -817,11 +817,21 @@ class ContinuousLMEngine(_SubmitRetryMixin):
     def _dispatch(self, table_rows: np.ndarray, toks: np.ndarray,
                   pos: np.ndarray, valid: np.ndarray) -> np.ndarray:
         """Run one paged model step; adopts the updated pools.  Returns
-        host logits (B, S, vocab)."""
+        host logits (B, S, vocab).  With spans on, ``lm.dispatch`` carries
+        the paged attention kernel's grid: ``grid_blocks`` (slots x table
+        blocks) and ``live_blocks``, those it computes (each slot's up to
+        the block of its last valid position)."""
         rec = self.spans
-        if rec is not None:
-            span = rec.begin()
         b = table_rows.shape[0]
+        if rec is not None:
+            from repro.kernels.attn_flash import paged_block_pages
+
+            k = paged_block_pages(self.page_size, self.table_pages)
+            n_blk = -(-self.table_pages // k)
+            last = np.where(valid > 0, pos + valid - 1, -1)
+            live = np.minimum(last // (self.page_size * k) + 1, n_blk)
+            grid = dict(live_blocks=int(live.sum()), grid_blocks=b * n_blk)
+            span = rec.begin()
         tbl = jnp.broadcast_to(
             jnp.asarray(table_rows, jnp.int32)[None],
             (self._n_layers, b, self.table_pages))
@@ -833,7 +843,7 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         self.stats["dispatches"] += 1
         if rec is None:
             return np.asarray(logits)
-        t = rec.end("lm.dispatch", span, step=self._step)
+        t = rec.end("lm.dispatch", span, step=self._step, **grid)
         wait = rec.begin(t)
         host = np.asarray(logits)
         rec.end("lm.logits_wait", wait, step=self._step)

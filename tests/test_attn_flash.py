@@ -204,42 +204,112 @@ def test_expand_kv_tp_padded_heads():
 # Paged attention: the Pallas kernel against its gather realization
 # ---------------------------------------------------------------------------
 
-def _paged_problem(S, heads, kv_heads, hd=16, ps=8, n_pages=6, seed=0):
-    """Two slots over a 6-page pool (+ null page 6).  Slot 0 owns pages
-    [3, 1] with positions 0..11 written (page 1 ragged); slot 1 owns page
-    [0] with positions 0..4.  Unowned pages hold stale K/V and stale
-    positions, which the table must never expose."""
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+def _paged_problem(S, heads, kv_heads, hd=16, ps=8, ctx=(12, 5), P=3,
+                   spare=None, seed=0):
+    """Slots over a shuffled page pool whose last page is the null page.
+
+    Slot b has positions ``0..ctx[b]-1`` written across ``ceil(ctx[b]/ps)``
+    pages (the last one ragged; ``ctx[b] = 0`` is an idle slot with no
+    valid query row), then ``spare[b]`` more pages in its table row that
+    hold a stale tenant's K/V and the positions of their own columns — all
+    past the slot's context, which causality must hide.  Four more pages
+    are owned by no table and hold stale K/V and positions the table must
+    never expose.  Rows pad to ``P`` columns with the null page.  The
+    queries are the last ``S`` positions of each context; rows before
+    position 0 are invalid (-1)."""
+    B = len(ctx)
+    spare = spare or (0,) * B
+    live = [-(-c // ps) for c in ctx]
+    owned = sum(live) + sum(spare)
+    n_pages = owned + 4
     null = n_pages
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     pool_k = jax.random.normal(ks[0], (n_pages + 1, ps, kv_heads, hd))
     pool_v = jax.random.normal(ks[1], (n_pages + 1, ps, kv_heads, hd))
+    rng = np.random.RandomState(seed)
+    order = iter(rng.permutation(n_pages))
     ppos = np.full((n_pages + 1, ps), -1, np.int32)
-    ppos[3] = np.arange(8)
-    ppos[1, :4] = np.arange(8, 12)
-    ppos[0, :5] = np.arange(5)
-    ppos[2] = np.arange(40, 48)        # stale tenant, not in any table
-    table = np.asarray([[3, 1, null], [0, null, null]], np.int32)
-    last = np.asarray([11, 4], np.int32)
+    table = np.full((B, P), null, np.int32)
+    for b in range(B):
+        for col in range(live[b] + spare[b]):
+            page = table[b, col] = next(order)
+            span = np.arange(col * ps, (col + 1) * ps)
+            ppos[page] = np.where(span < ctx[b], span, -1) if col < live[b] \
+                else span
+    for page in order:                 # unowned stale tenants
+        ppos[page] = rng.randint(0, 4 * ps, size=ps)
+    last = np.asarray(ctx, np.int32) - 1
     q_pos = last[:, None] - (S - 1) + np.arange(S, dtype=np.int32)[None]
-    q = jax.random.normal(ks[2], (2, S, heads, hd))
+    q_pos = np.where(q_pos >= 0, q_pos, -1)
+    q = jax.random.normal(ks[2], (B, S, heads, hd))
     return q, pool_k, pool_v, jnp.asarray(ppos), jnp.asarray(table), \
         jnp.asarray(q_pos)
 
 
-@pytest.mark.parametrize("S,heads,kv_heads", [(1, 4, 2), (4, 3, 3),
-                                               (4, 6, 2)])
-def test_paged_pallas_matches_gather_realization(S, heads, kv_heads):
+# Paged geometries beside the base one (two slots, 8-token pages, a 3-page
+# table): 16-token pages take 8 pages a kernel block, 8-token pages 16.
+PAGED_GEOMETRIES = {
+    "base": {},
+    # an idle slot; a 10-page table, not a multiple of the 8-page block
+    "idle-slot": dict(ps=16, P=10, ctx=(0, 40, 150)),
+    # live pages ending exactly at a block edge (8) and mid-block (13)
+    "block-edge": dict(ps=16, P=20, ctx=(128, 200)),
+    # stale pages past the live bound: slot 0's reach into a skipped block
+    "stale-past-live": dict(ps=16, P=20, ctx=(70, 150), spare=(5, 2)),
+    # 8-token pages: 16 a block; a block edge, mid-block, an idle slot
+    "page-8": dict(ps=8, P=40, ctx=(128, 300, 0), spare=(3, 0, 0)),
+}
+
+
+@pytest.mark.parametrize("S,heads,kv_heads,geom", [
+    pytest.param(1, 4, 2, "base", id="1-4-2"),
+    pytest.param(4, 3, 3, "base", id="4-3-3"),
+    pytest.param(4, 6, 2, "base", id="4-6-2"),
+    pytest.param(1, 3, 1, "idle-slot", id="1-3-1-idle-slot"),
+    pytest.param(4, 6, 2, "idle-slot", id="4-6-2-idle-slot"),
+    pytest.param(1, 4, 2, "block-edge", id="1-4-2-block-edge"),
+    pytest.param(4, 3, 3, "block-edge", id="4-3-3-block-edge"),
+    pytest.param(1, 6, 2, "stale-past-live", id="1-6-2-stale-past-live"),
+    pytest.param(4, 4, 2, "stale-past-live", id="4-4-2-stale-past-live"),
+    pytest.param(1, 4, 2, "page-8", id="1-4-2-page-8"),
+    pytest.param(4, 3, 1, "page-8", id="4-3-1-page-8"),
+])
+def test_paged_pallas_matches_gather_realization(S, heads, kv_heads, geom):
     """The Pallas paged kernel (interpret mode) computes the same
     attention as ``attn_paged_xla``: exact int32 logits, so only the
     online softmax's f32 summation order separates them — decode (S=1)
-    and prefill-chunk (S=4) shapes, GQA, ragged pages, null-page padding
-    and stale unowned pages."""
-    args = _paged_problem(S, heads, kv_heads)
+    and prefill-chunk (S=4) shapes, GQA, ragged pages, null-page padding,
+    stale unowned pages, stale pages past a slot's live bound, idle
+    slots, live pages ending on and between block edges, and tables that
+    are not a whole number of blocks.  Invalid query rows (an idle slot's)
+    have no softmax to compare; the kernel writes them as zeros."""
+    args = _paged_problem(S, heads, kv_heads, **PAGED_GEOMETRIES[geom])
     ref = attn_paged_xla(*args, quantized=True, bits=8, n_q_heads=heads)
     out = attn_paged_pallas(*args, bits=8, n_q_heads=heads, interpret=True)
     assert out.shape == ref.shape
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    valid = np.asarray(args[-1]) >= 0
+    np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(ref)[valid],
                                atol=2e-5, rtol=0)
+    assert not np.asarray(out)[~valid].any()
+
+
+def test_paged_pallas_slot_output_independent_of_other_slots():
+    """A slot's output bits do not depend on the other slots' lengths:
+    idle, shortened (fewer live blocks over the same pages) or longer
+    than it, with other queries."""
+    q, pk, pv, ppos, table, q_pos = _paged_problem(
+        1, 6, 2, **PAGED_GEOMETRIES["page-8"])
+    base = attn_paged_pallas(q, pk, pv, ppos, table, q_pos, bits=8,
+                             n_q_heads=6, interpret=True)
+    null = pk.shape[0] - 1
+    idle = (table.at[1:].set(null), q_pos.at[1:].set(-1))
+    short = (table, q_pos.at[1:].set(jnp.asarray([[5], [200]])))
+    q2 = q.at[1:].set(jax.random.normal(jax.random.PRNGKey(9), q[1:].shape))
+    for tbl, qp in (idle, short):
+        out = attn_paged_pallas(q2, pk, pv, ppos, tbl, qp, bits=8,
+                                n_q_heads=6, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out[0]),
+                                      np.asarray(base[0]))
 
 
 # ---------------------------------------------------------------------------

@@ -218,6 +218,37 @@ def test_lm_token_times_and_step_spans():
         range(eng.stats["steps"]))
 
 
+def test_lm_dispatch_counts_paged_kernel_blocks(monkeypatch):
+    """``lm.dispatch`` carries the paged kernel's grid: 64-token pages over
+    a 4-page table make blocks of 2 pages (128 keys), 2 a slot.  Request 0
+    (126-token prompt, 4 tokens) prefills in two chunks, request 1 (3
+    tokens, 2 out) in one, each one live block of 2; the decode steps
+    write positions (126, 3), (127, idle), (128, idle): live 1 + 1, 1 + 0
+    and 2 + 0 of 2 x 2.  With spans off nothing is counted."""
+    from repro.kernels import attn_flash
+
+    def make():
+        return ContinuousLMEngine(LM_PARAMS, LM_CFG, num_slots=2,
+                                  page_size=64, num_pages=8, max_seq=256)
+
+    payloads = [(np.arange(126, dtype=np.int32) % 60 + 1, 4),
+                (np.arange(3, dtype=np.int32) + 1, 2)]
+    eng = make()
+    rec = eng.record_spans()
+    on = eng.serve(payloads)
+    assert [(d["live_blocks"], d["grid_blocks"])
+            for d in rec.records["lm.dispatch"]] == [
+        (1, 2), (1, 2), (1, 2), (2, 4), (1, 4), (2, 4)]
+
+    def refuse(*a):
+        raise AssertionError("counted with spans off")
+
+    monkeypatch.setattr(attn_flash, "paged_block_pages", refuse)
+    off = make().serve(payloads)
+    for a, b in zip(on, off):
+        np.testing.assert_array_equal(a.value, b.value)
+
+
 def test_lm_spans_survive_a_power_loss(tmp_path):
     """A power loss inside an admission's prefill unwinds past its open
     span: later spans still nest, and resumed requests keep one stamp per

@@ -146,12 +146,19 @@ def test_attn_flash_compiles_at_smollm_prefill(one_chip):
     assert "tpu_custom_call" in _compile(one_chip, fn, qkv, qkv, qkv)
 
 
-@pytest.mark.parametrize("batch,seq", [(4, 1), (1, 16)])
-def test_attn_paged_compiles_at_smollm_serve(one_chip, batch, seq):
-    """The continuous engine's two paged shapes for smollm-360m: a 4-slot
-    decode step and a 16-token prefill chunk, over 16-token bf16 pages
-    of 5 KV heads and an 18-page table."""
-    n_pages, ps, hkv, hd, table = 108, 16, 5, 64, 18
+@pytest.mark.parametrize("batch,seq,n_pages,table", [
+    pytest.param(4, 1, 108, 18, id="4-1"),
+    pytest.param(1, 16, 108, 18, id="1-16"),
+    pytest.param(16, 1, 2048, 128, id="16-1-cell"),
+    pytest.param(1, 16, 2048, 128, id="1-16-cell"),
+])
+def test_attn_paged_compiles_at_smollm_serve(one_chip, batch, seq, n_pages,
+                                             table):
+    """The continuous engine's two paged shapes for smollm-360m: a decode
+    step and a 16-token prefill chunk, over 16-token bf16 pages of 5 KV
+    heads; an 18-page table over 108 pages, and the chat-offline cell's
+    geometry (16 slots, a 128-page table over 2,048 pages)."""
+    ps, hkv, hd = 16, 5, 64
 
     def fn(q, pk, pv, ppos, tbl, qpos):
         return attn_paged_pallas(q, pk, pv, ppos, tbl, qpos, bits=8,
