@@ -12,9 +12,9 @@ Three CI gates (enforced in every mode; ``--fast`` shrinks the fleet):
   * determinism — the entire seeded study runs TWICE and the serialized
     aggregate reports must match bit-for-bit (same seed -> same bytes);
   * validation — one node's derived outage schedule replays through a REAL
-    ``ResilientServeEngine`` and the simulator's engine-accounting mirror
-    must agree: integer work counters exactly, float accounting within
-    1e-6 (the DESIGN.md §14 contract);
+    continuous LM engine (the resilient facade) and the simulator's
+    engine-accounting mirror must agree: integer work counters exactly,
+    float accounting within 1e-6 (the DESIGN.md §14 contract);
   * co-design win — aggregate inferences/day must beat the baseline while
     every node meets its SLO.
 
@@ -36,7 +36,7 @@ SEED = 0          # fleet trace seed
 SLO_SEED = 1      # per-node accuracy-SLO draw
 RESUME_US = 26_000.0   # post-outage plan reload (cf. plan_resume_study)
 
-# smoke-LM replay geometry (matches bench_resilience's serving story)
+# smoke-LM replay geometry
 N_REQUESTS = 8
 NEW_TOKENS = 7
 EPOCH_STEPS = 2
@@ -75,8 +75,10 @@ def _study(n_nodes: int):
 
 def _validate(traces, assignments, results):
     """Replay the busiest node's outage schedule through the live engine."""
-    from repro.fleet import (NodeConfig, epoch_schedule, frame_cost_table,
-                             live_validation, rescale_outages, simulate_node)
+    from repro.fleet import (NodeConfig, frame_cost_table, live_validation,
+                             predict_engine_stats, rescale_outages,
+                             simulate_node)
+    from repro.resilience import FaultPlan
 
     # the node with the most outages gives the densest replay schedule
     idx = max(range(len(results)), key=lambda i: results[i]["failures"])
@@ -92,8 +94,9 @@ def _validate(traces, assignments, results):
     outages = r["outage_frames"]
     # compress the day-scale schedule onto ~80% of the replay's fault-free
     # work so the kills land mid-decode, not all at t=0
-    engine_work = 0.8 * (-(-N_REQUESTS // MAX_BATCH)) * (
-        0.25 + 1.0 + sum(epoch_schedule(NEW_TOKENS, EPOCH_STEPS)))
+    engine_work = 0.8 * predict_engine_stats(
+        FaultPlan(None), n_requests=N_REQUESTS, new_tokens=NEW_TOKENS,
+        epoch_steps=EPOCH_STEPS, max_batch=MAX_BATCH)["dispatches"]
     sched = (rescale_outages(outages, outages[-1], engine_work)
              if outages else [])
     ckdir = tempfile.mkdtemp(prefix="fleet_val_")

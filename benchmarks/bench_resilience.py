@@ -3,29 +3,32 @@
 The analytic intermittency model (``pim/intermittent.forward_progress``,
 paper Fig. 7) predicts how much useful work survives random power failures
 as a function of MTBF and checkpoint period P.  This benchmark measures
-the same quantity on the executing stack: a
-:class:`repro.resilience.ResilientServeEngine` serving LM generate
-requests under a seeded exponential :class:`~repro.resilience.FaultPlan`,
-with the scanned decode segmented into K-step epochs committed through the
-atomic checkpointer (K = P).  Both curves land side by side in
-``results/bench_resilience.json``.
+the same quantity on the executing stack: an LM deployment from
+``compiled.serve(resilience=...)`` — the continuous-batching engine —
+serving generate requests under a seeded exponential
+:class:`~repro.resilience.FaultPlan`, with decode epoch checkpoints every
+K decode steps through the atomic checkpointer (K = P).  Both curves land
+side by side in ``results/bench_resilience.json``.
 
-Units: the engine's fault clock counts **decode steps** ("frames"); one
-bucket's sequence is ``new_tokens - 1`` frames.  Measured efficiency is
-useful steps over total charged work (executed + wasted partial windows +
-prefill/restore restarts + checkpoint writes priced in step units, from
-the measured commit/step wall-time ratio); the analytic arm runs
+Units: the engine's fault clock counts **dispatches** ("frames"): one per
+prefill chunk and one per decode step.  A kill resumes from the last
+commit (cold with P = 0), and the engine enqueues again, under its rid,
+every request the restored schedule does not hold, so every prompt gets
+one result.
+Measured efficiency is the fault-free run's dispatches over total charged
+work (dispatches executed, redone ones included, + the partial windows
+the kills cut short + checkpoint writes priced in dispatch units, from
+the measured commit/dispatch span-time ratio) —
+``repro.fleet.measured_efficiency``.  The analytic arm runs
 ``forward_progress`` on the identical (MTBF, P) grid with the same
-measured ``nv_write`` cost, averaged over one seed per served bucket.
+measured ``nv_write`` cost, over one wave of ``MAX_BATCH`` requests.
 
 Hard assertions (the CI chaos gate, ``--fast``):
-  * every completed request under chaos is bit-identical to the fault-free
-    run at the same checkpoint period (same composition, same programs);
-  * no dead letters anywhere in the sweep (retries are effectively
-    unbounded there);
-  * at the HIGHEST fault rate, a bounded-retry engine with a pre-compiled
-    lower-bit fallback plan degrades instead of dead-lettering: the paper's
-    accuracy-for-progress trade, executed.
+  * every request under chaos is bit-identical to the fault-free run;
+  * no dead letters anywhere in the sweep;
+  * at the HIGHEST fault rate, a bounded-retry CNN engine with a
+    pre-compiled lower-bit fallback plan degrades instead of
+    dead-lettering: the paper's accuracy-for-progress trade, executed.
 
 Run standalone::
 
@@ -40,22 +43,22 @@ import json
 import os
 import shutil
 import tempfile
-import time
 
 import numpy as np
 
 PROMPT_LEN = 8
-NEW_TOKENS = 9            # 8 decode steps = 8 "frames" per bucket sequence
+NEW_TOKENS = 9            # 8 decode steps per request
 MAX_BATCH = 4
 
 
 def _build(fast: bool):
     import jax
 
+    from repro import api
     from repro.configs import SINGLE, all_configs
-    from repro.core.plan import compile_lm
     from repro.core.quant import PAPER_CONFIGS
     from repro.models import transformer as T
+    from repro.models.cnn import init_cnn, svhn_cnn_spec
 
     cfg = dataclasses.replace(
         all_configs()["smollm-360m"].smoke(
@@ -63,126 +66,88 @@ def _build(fast: bool):
             vocab=64, head_dim=32),
         quant=PAPER_CONFIGS["w1a8"])
     params, _ = T.init_lm(jax.random.PRNGKey(0), cfg, SINGLE)
-    plan8 = compile_lm(params, cfg, batch_hints=(1, MAX_BATCH),
-                       prompt_len=PROMPT_LEN)
-    cfg4 = dataclasses.replace(cfg, quant=PAPER_CONFIGS["w1a4"])
-    plan4 = compile_lm(params, cfg4, batch_hints=(1, MAX_BATCH),
-                       prompt_len=PROMPT_LEN)
+    lm = api.build(cfg, params=params).compile(batch_hints=(1, MAX_BATCH),
+                                               prompt_len=PROMPT_LEN)
     n_req = 8 if fast else 16
     prompts = [np.random.RandomState(i).randint(0, cfg.vocab,
                                                 size=(PROMPT_LEN,))
                .astype(np.int32) for i in range(n_req)]
-    return cfg, cfg4, plan8, plan4, prompts
+    # the degrade arm: the svhn CNN at w1a8, with a cheaper w1a4 fallback
+    spec = svhn_cnn_spec(8)
+    cparams, _ = init_cnn(jax.random.PRNGKey(0), spec)
+    cnn8, cnn4 = (api.build(spec, PAPER_CONFIGS[q], params=cparams,
+                            img_hw=16).compile(batch_hints=(1, MAX_BATCH))
+                  for q in ("w1a8", "w1a4"))
+    imgs = [np.random.RandomState(i).uniform(size=(16, 16, 3))
+            .astype(np.float32) for i in range(n_req)]
+    return lm, prompts, cnn8, cnn4, imgs
 
 
-def _engine(cfg, plan, k: int, ckdir, **kw):
-    from repro.resilience import EpochLMRunner, ResilientServeEngine
+def _engine(lm, k: int, ckdir: str, fault_plan=None):
+    """A fresh resilient deployment: checkpoints every ``k`` decode steps,
+    none for ``k == 0`` (the volatile P=0 baseline)."""
+    from repro.resilience import ResilienceConfig
 
-    runner = EpochLMRunner(None, cfg, new_tokens=NEW_TOKENS,
-                           epoch_steps=(k if k else 1), model_plan=plan)
-    return ResilientServeEngine(runner, checkpoint_dir=ckdir,
-                                max_batch=MAX_BATCH, **kw)
-
-
-def _reset(eng, fault_plan) -> None:
-    """Point one warmed engine (hot jit cache) at a fresh chaos run."""
-    from repro.resilience import FaultPlan
-
-    eng.faults = fault_plan if fault_plan is not None else FaultPlan(None)
-    for key in eng.stats:
-        eng.stats[key] = 0.0 if isinstance(eng.stats[key], float) else 0
-    eng.dead_letters.clear()
-    eng.result_runner.clear()
-    eng._attempts.clear()
-    eng._retry.clear()
-    if eng._active:              # undo a previous run's degrade swap
-        eng._active = 0
-        eng._energy_scale = 1.0
-        eng.runner = eng._runners[0]
-        import jax
-
-        eng._params = jax.device_put(eng.runner.params)
-    if eng.policy is not None:
-        eng.policy.reset()
-    if eng.ckpt is not None:
-        eng.ckpt.purge_all()
-
-
-def _run(eng, prompts, fault_plan):
-    _reset(eng, fault_plan)
-    t0 = time.perf_counter()
-    results = eng.serve(list(prompts))
-    wall = time.perf_counter() - t0
-    return results, wall
-
-
-def _measured_efficiency(stats, nv_write_steps: float) -> float:
-    """Useful frames over total charged work, in decode-step units.
-
-    executed_steps already contains every re-executed (lost) epoch;
-    wasted_steps adds the partial window each kill destroyed; commits
-    charge the measured NV-write cost.  Restarts (extra prefills/restores
-    beyond each completed bucket's one) charge one frame each — matching
-    the analytic model's ``resume_us``, which is likewise only paid after
-    a failure."""
-    restarts = max(0.0, stats["prefills"] + stats["resumes"]
-                   - stats["dispatches"])
-    total = (stats["executed_steps"] + stats["wasted_steps"] + restarts
-             + nv_write_steps * stats["commits"])
-    return stats["useful_steps"] / total if total else 0.0
+    return lm.serve(max_batch=MAX_BATCH, new_tokens=NEW_TOKENS,
+                    resilience=ResilienceConfig(
+                        fault_plan=fault_plan,
+                        checkpoint_dir=ckdir if k else None,
+                        epoch_steps=k or 1)).engine
 
 
 def resilience_rows(fast: bool = False) -> list:
+    from repro.fleet import measured_efficiency
     from repro.pim.intermittent import forward_progress
-    from repro.resilience import DegradePolicy, FaultPlan
+    from repro.resilience import DegradePolicy, FaultPlan, ResilienceConfig
 
-    cfg, cfg4, plan8, plan4, prompts = _build(fast)
-    frames = NEW_TOKENS - 1
-    n_buckets = len(prompts) // MAX_BATCH
+    lm, prompts, cnn8, cnn4, imgs = _build(fast)
+    n_waves = -(-len(prompts) // MAX_BATCH)
     mtbfs = (16.0, 48.0) if fast else (8.0, 16.0, 32.0, 64.0)
     periods = (0, 2, 4) if fast else (0, 1, 2, 4)
     root = tempfile.mkdtemp(prefix="bench_resilience_")
     rows = []
     mismatches = dead = 0
     try:
-        # one engine per checkpoint period: different K = different scan
-        # programs (its own jit cache, its own fault-free reference — bit
-        # identity is a same-program property)
-        step_us = nv_write_steps = None
+        # price one NV commit in dispatch units from a fault-free run's
+        # spans (the same number feeds the analytic arm)
+        eng = _engine(lm, 2, os.path.join(root, "price"))
+        ref = [r.value for r in eng.serve(prompts)]   # compiles the programs
+        useful = eng.stats["dispatches"]
+        commits = eng.stats["commits"]
+        rec = eng.record_spans()
+        eng.serve(prompts)                        # warm: the priced run
+        frames = useful // n_waves
+        t_disp = sum(r["dt"] for n in ("lm.prefill_chunk", "lm.decode_step")
+                     for r in rec.records.get(n, ()))
+        t_commit = sum(r["dt"] for r in rec.records.get("lm.commit", ()))
+        step_us = t_disp * 1e6 / useful
+        nv_write_steps = (t_commit / commits) / (t_disp / useful)
         for k in periods:
-            ckdir = os.path.join(root, f"k{k}") if k else None
-            eng = _engine(cfg, plan8, k, ckdir, max_retries=10_000)
-            _run(eng, prompts, None)                   # warm the jit cache
-            ref_res, wall = _run(eng, prompts, None)   # fault-free reference
-            # rids keep incrementing across runs of one engine: results come
-            # back rid-sorted = submission-ordered, so compare by position
-            ref = [r.value for r in ref_res]
-            s = eng.stats
-            if k and nv_write_steps is None:
-                # price one NV commit in decode-step units, from the warmed
-                # fault-free run (same numbers feed the analytic arm)
-                step_us = ((wall - s["commit_s"]) * 1e6
-                           / (s["executed_steps"] + s["prefills"]))
-                commit_us = s["commit_s"] * 1e6 / s["commits"]
-                nv_write_steps = commit_us / step_us
             for mtbf in mtbfs:
-                res, _ = _run(eng, prompts, FaultPlan(mtbf, seed=17))
-                got = [r.value for r in res]
+                faults = FaultPlan(mtbf, seed=17)
+                eng = _engine(lm, k, os.path.join(root, f"k{k}_{mtbf:g}"),
+                              faults)
+                got = [r.value for r in eng.serve(prompts)]
                 bit_identical = (len(got) == len(ref) and all(
                     np.array_equal(g, r) for g, r in zip(got, ref)))
                 mismatches += not bit_identical
                 dead += len(eng.dead_letters)
-                measured = _measured_efficiency(eng.stats,
-                                                nv_write_steps or 0.0)
-                # the measured arm is ONE seeded realization over n_buckets
-                # sequences; the analytic arm reports the model expectation
-                # (32 seeds) on the same (MTBF, P, nv_write) point
+                stats = dict(
+                    eng.stats, useful_dispatches=useful,
+                    wasted_steps=sum(e.offset for e in faults.log
+                                     if e.kind in ("power_loss",
+                                                   "device_drop")))
+                measured = measured_efficiency(
+                    stats, nv_write_steps if k else 0.0)
+                # the measured arm is ONE seeded realization; the analytic
+                # arm reports the model expectation (32 seeds) on the same
+                # (MTBF, P, nv_write) point, over one wave of requests
                 analytic = float(np.mean([
                     forward_progress(
                         n_frames=frames, frame_time_us=1.0, mtbf_us=mtbf,
                         checkpoint_period_frames=k,
-                        nv_write_us=nv_write_steps or 0.0, resume_us=1.0,
-                        seed=100 * i + 7)["efficiency"]
+                        nv_write_us=nv_write_steps if k else 0.0,
+                        resume_us=1.0, seed=100 * i + 7)["efficiency"]
                     for i in range(32)]))
                 rows.append(dict(
                     name=f"resilience_mtbf{mtbf:g}_k{k}", kind="chaos",
@@ -192,45 +157,38 @@ def resilience_rows(fast: bool = False) -> list:
                     analytic_efficiency=round(analytic, 4),
                     bit_identical=bit_identical,
                     dead_letters=len(eng.dead_letters),
-                    faults=eng.stats["faults"],
-                    retries=eng.stats["retries"],
-                    resumes=eng.stats["resumes"],
+                    power_losses=eng.stats["power_losses"],
                     commits=eng.stats["commits"],
-                    executed_steps=eng.stats["executed_steps"],
-                    useful_steps=eng.stats["useful_steps"],
-                    wasted_steps=round(eng.stats["wasted_steps"], 2)))
+                    dispatches=eng.stats["dispatches"],
+                    useful_dispatches=useful,
+                    wasted_steps=round(stats["wasted_steps"], 2)))
 
-        # degraded-plan fallback at the benchmark's highest fault rate
-        # (harsher than any sweep cell): bounded retries would dead-letter
-        # on the w1a8 plan alone; after the degrade swap the w1a4 fallback
-        # sees a ~1.6x longer energy-MTBF per step and must keep serving
-        # with NO dead letters (ISSUE acceptance criterion)
-        worst = 4.0
-        from repro.resilience import EpochLMRunner
-
-        fb = EpochLMRunner(None, cfg4, new_tokens=NEW_TOKENS, epoch_steps=2,
-                           model_plan=plan4)
-        deg = _engine(cfg, plan8, 2, os.path.join(root, "deg"),
-                      max_retries=5, fallbacks=(fb,),
-                      degrade=DegradePolicy(fault_window=4,
-                                            fault_threshold=2))
-        _run(deg, prompts, None)                       # warm
-        res, _ = _run(deg, prompts, FaultPlan(worst, seed=23))
+        # degraded-plan fallback on the CNN engine (the LM engine has no
+        # degrade path), at the benchmark's highest fault rate: one fault
+        # per image dispatch on average, harsher than any sweep cell.
+        # After the degrade swap the cheaper w1a4 fallback sees a longer
+        # energy-MTBF per dispatch and must keep serving with NO dead
+        # letters
+        worst = 1.0
+        deg = cnn8.serve(resilience=ResilienceConfig(
+            fault_plan=FaultPlan(worst, seed=23), max_retries=5,
+            degrade=DegradePolicy(fault_window=4, fault_threshold=2)),
+            fallback=cnn4, max_batch=1).engine
+        res = deg.serve(imgs)
         rows.append(dict(
             name="resilience_degrade", kind="degrade", mtbf_steps=worst,
-            checkpoint_period=2, n_requests=len(prompts),
-            completed=len(res), degrades=deg.stats["degrades"],
-            faults=deg.stats["faults"],
+            n_requests=len(imgs), completed=len(res),
+            degrades=deg.stats["degrades"], faults=deg.stats["faults"],
             dead_letters=len(deg.dead_letters),
             served_by_fallback=sum(v == 1
                                    for v in deg.result_runner.values()),
             energy_pj=round(deg.stats["energy_pj"], 1)))
-        degrade_ok = (len(res) == len(prompts) and not deg.dead_letters
+        degrade_ok = (len(res) == len(imgs) and not deg.dead_letters
                       and deg.stats["degrades"] >= 1)
         rows.append(dict(
             name="resilience_summary", kind="summary",
-            step_us=round(step_us or 0.0, 2),
-            nv_write_steps=round(nv_write_steps or 0.0, 4),
+            step_us=round(step_us, 2),
+            nv_write_steps=round(nv_write_steps, 4),
             bit_identity_mismatches=mismatches,
             sweep_dead_letters=dead, degrade_ok=degrade_ok))
     finally:

@@ -14,10 +14,9 @@ CNN e2e compares three dataflows (layer-level numbers live in
                 the implicit-GEMM engine (no patch bytes), the rest to the
                 PR-1 engines.
 
-Transformer decode compares the seed per-token Python loop (one jitted
-step re-dispatched from the host, argmax synced per token) against the
-``lax.scan`` generate in ``repro.launch.serve`` — cold (incl. compile) and
-warm reported separately.
+The LM rows drive the continuous-batching engine
+(``repro.launch.engine.ContinuousLMEngine``) on a mixed prompt/horizon
+mix: the restart arm (empty jit cache) and the warm steady state.
 
 Emits the repo's ``name,us_per_call,derived`` CSV plus
 ``results/bench_serve.json``.  Run standalone::
@@ -210,85 +209,6 @@ def plan_rows(fast: bool = False):
 
 
 # ---------------------------------------------------------------------------
-# Transformer decode: python-loop (seed) vs lax.scan generate
-# ---------------------------------------------------------------------------
-
-def _loop_decode(params, cfg, plan, prompts, new_tokens: int, qmode: str,
-                 prefill=None, step=None):
-    """The seed decode: host loop re-dispatching one jitted step per token,
-    with a device->host argmax sync in between.  Pass pre-built ``prefill``
-    / ``step`` so the warm measurement reuses the jit cache (like a
-    long-lived server would); the prefill is jitted the same way as the
-    scan path's, so warm loop-vs-scan isolates the DECODE dispatch gap.
-    The argmax uses the same real-vocab mask as the scan path (the row
-    compares dispatch strategies; vocab policy must not differ)."""
-    from repro.launch.serve import greedy_token, grow_cache, make_prefill
-    from repro.models import transformer as T
-
-    B, S_p = prompts.shape
-    prefill = prefill or make_prefill(params, cfg, plan, qmode)
-    step = step or jax.jit(
-        lambda c, t, p: T.decode_step(params, c, t, p, cfg, plan,
-                                      qmode=qmode))
-    t0 = time.perf_counter()
-    logits, cache = prefill(prompts)
-    cache = grow_cache(cache, S_p, S_p + new_tokens)
-    tok = greedy_token(logits, cfg.vocab)
-    toks = [tok]
-    for t in range(new_tokens - 1):
-        lg, cache = step(cache, tok, S_p + t)
-        tok = greedy_token(lg, cfg.vocab)
-        toks.append(tok)
-    gen = jnp.concatenate(toks, axis=1)
-    jax.block_until_ready(gen)
-    return gen, time.perf_counter() - t0, prefill, step
-
-
-def decode_rows(fast: bool = False):
-    from repro.configs import SINGLE, get_config
-    from repro.core.quant import PAPER_CONFIGS
-    from repro.data.synthetic import lm_batch
-    from repro.launch.serve import make_generate, make_prefill, serve_once
-    from repro.models import transformer as T
-
-    cfg = dataclasses.replace(get_config("phi3-mini-3.8b").smoke(),
-                              quant=PAPER_CONFIGS["w1a8"])
-    qmode = "serve"
-    B, S_p, S_d = 2, 8, 8 if fast else 16
-    params, _ = T.init_lm(jax.random.PRNGKey(0), cfg, SINGLE)
-    prompts = jnp.asarray(
-        lm_batch(0, 0, batch=B, seq=S_p, vocab=cfg.vocab)["tokens"])
-
-    loop_gen, loop_cold, pf, step = _loop_decode(params, cfg, SINGLE,
-                                                 prompts, S_d, qmode)
-    _, loop_warm, _, _ = _loop_decode(params, cfg, SINGLE, prompts, S_d,
-                                      qmode, prefill=pf, step=step)
-
-    prefill_fn = make_prefill(params, cfg, SINGLE, qmode)
-    generate_fn = make_generate(params, cfg, SINGLE, qmode, S_p, S_d)
-    scan_gen, scan_cold = serve_once(params, cfg, SINGLE, prompts, S_d,
-                                     qmode, prefill_fn, generate_fn)
-    _, scan_warm = serve_once(params, cfg, SINGLE, prompts, S_d, qmode,
-                              prefill_fn, generate_fn)
-    # the two paths are separately compiled float programs, so an argmax
-    # near-tie can legitimately flip a token (ulp-level logit reordering);
-    # report the comparison instead of asserting it so the --strict CI
-    # gate cannot flake on it
-    tokens_match = bool((jnp.asarray(scan_gen) == jnp.asarray(loop_gen)).all())
-    return [dict(
-        name="decode_scan", kind="decode", arch=cfg.name, batch=B,
-        prompt_len=S_p, new_tokens=S_d, quant="w1a8",
-        tokens_match_loop=tokens_match,
-        loop_cold_us=round(loop_cold * 1e6),
-        loop_warm_us=round(loop_warm * 1e6),
-        scan_cold_us=round(scan_cold * 1e6),
-        scan_warm_us=round(scan_warm * 1e6),
-        tok_s_cold=round(B * S_d / scan_cold, 1),
-        tok_s_warm=round(B * S_d / scan_warm, 1),
-        warm_speedup=round(loop_warm / scan_warm, 2))]
-
-
-# ---------------------------------------------------------------------------
 # Request-level throughput: the serving engine under load (PR 3)
 # ---------------------------------------------------------------------------
 
@@ -297,7 +217,7 @@ def decode_rows(fast: bool = False):
 def throughput_rows(fast: bool = False):
     """Offered-load sweep through ``repro.launch.engine.ServeEngine``.
 
-    Per workload (CNN serve forward, LM generate):
+    On the CNN serve forward:
       * ``seq_rps``      closed-loop requests/s with ``max_batch=1`` — the
                          sequential per-request dispatch baseline;
       * ``batch8_rps``   closed-loop with ``max_batch=8`` (coalesced
@@ -308,10 +228,9 @@ def throughput_rows(fast: bool = False):
     import numpy as np
 
     from repro.core.plan import compile_model
-    from repro.core.quant import PAPER_CONFIGS, W1A4
-    from repro.launch.engine import (CNNRunner, LMRunner, ServeEngine,
-                                     run_offered_load)
-    from repro.models import transformer as T
+    from repro.core.quant import W1A4
+    from repro.launch.engine import (CNNRunner, ServeEngine,
+                                     run_offered_load, warm_engine)
     from repro.models.cnn import init_cnn, svhn_cnn_spec
 
     n_req = 24 if fast else 48
@@ -335,26 +254,7 @@ def throughput_rows(fast: bool = False):
                                    max_batch=max_batch,
                                    flush_deadline_s=0.002, max_pending=16)
 
-    # LM workload: prefill + scanned greedy decode per request, projection
-    # engines resolved once into the plan's dense verdict table
-    from repro.core.plan import compile_lm
-
-    cfg = dataclasses.replace(get_smoke_lm(), quant=PAPER_CONFIGS["w1a8"])
-    lparams, _ = T.init_lm(jax.random.PRNGKey(0), cfg, _single_plan())
-    lm_plan = compile_lm(lparams, cfg, batch_hints=(1, 8), prompt_len=8)
-    prompts = [np.random.RandomState(i).randint(0, cfg.vocab, size=(8,))
-               .astype(np.int32) for i in range(n_req)]
-
-    def lm_engine(max_batch):
-        return lambda: ServeEngine(
-            LMRunner(None, cfg, new_tokens=8, qmode="serve",
-                     model_plan=lm_plan),
-            max_batch=max_batch, flush_deadline_s=0.002, max_pending=16)
-
-    from repro.launch.engine import warm_engine
-
-    for name, payloads, mk in (("cnn_svhn", imgs, cnn_engine),
-                               ("lm_decode", prompts, lm_engine)):
+    for name, payloads, mk in (("cnn_svhn", imgs, cnn_engine),):
         seq = run_offered_load(warm_engine(mk(1)(), payloads), payloads,
                                rate_rps=None)
         bat_eng = warm_engine(mk(8)(), payloads)
@@ -382,14 +282,12 @@ def throughput_rows(fast: bool = False):
 
 
 def continuous_rows(fast: bool = False):
-    """Continuous batching vs bucket dispatch on a MIXED prompt/horizon mix.
+    """The continuous-batching LM engine on a MIXED prompt/horizon mix.
 
-    The bucket engine fragments a mixed-length workload into one closed
-    bucket per (prompt-len, horizon) shape — short requests wait on long
-    scans (head-of-line blocking) and ragged buckets pad.  The continuous
-    engine admits at step granularity into a persistent paged-KV decode
-    batch, so the headline comparison is p99 latency + achieved req/s on
-    the same offered load.  Also gates (returned, asserted by the CI fast
+    The engine admits at step granularity into a persistent paged-KV
+    decode batch.  Reported: req/s and p99 latency in the restart arm (a
+    fresh server, empty jit cache) and in the warm steady state, plus an
+    offered-load sweep.  Also gates (returned, asserted by the CI fast
     lane via ``--continuous``):
 
       * decode bit-identity: the batched continuous run's tokens equal a
@@ -403,8 +301,7 @@ def continuous_rows(fast: bool = False):
 
     from repro.core.plan import compile_lm
     from repro.core.quant import PAPER_CONFIGS
-    from repro.launch.engine import (ContinuousLMEngine, LMRunner,
-                                     ServeEngine, run_offered_load,
+    from repro.launch.engine import (ContinuousLMEngine, run_offered_load,
                                      warm_engine)
     from repro.models import transformer as T
 
@@ -435,37 +332,12 @@ def continuous_rows(fast: bool = False):
             num_pages=num_pages, max_seq=max_seq, new_tokens=max(gens),
             qmode="serve", model_plan=lm_plan, max_pending=max(16, n_req))
 
-    def mk_bucket():
-        return ServeEngine(
-            LMRunner(None, cfg, new_tokens=max(gens), qmode="serve",
-                     model_plan=lm_plan),
-            max_batch=num_slots, flush_deadline_s=0.002,
-            max_pending=max(16, n_req))
-
-    # -- restart arm: a FRESH server meets the mixed mix (empty jit cache).
-    # The bucket engine compiles one scan program per (prompt-len, horizon,
-    # padded-batch) combination it dispatches — the mix's combinatorics land
-    # in its p99 — where the continuous engine compiles its three programs
-    # and is done.  This is the bounded-jit-cache claim measured, and the
-    # arm a power-intermittent node actually lives in.
-    restart_b = run_offered_load(mk_bucket(), payloads, rate_rps=None)
+    # -- restart arm: a FRESH server meets the mixed mix (empty jit cache):
+    # three programs compile, whatever the mix — the arm a
+    # power-intermittent node actually lives in
     restart_c = run_offered_load(mk_cont(), payloads, rate_rps=None)
-
-    # -- warm steady-state arm: every program either engine can dispatch is
-    # pre-compiled.  warm_engine only covers the first payload's shape key;
-    # a mixed mix dispatches every (key, padded-size) combination, and any
-    # cold compile inside a measured run would be charged to the bucket arm
-    bucket = warm_engine(mk_bucket(), payloads)
-    by_key = {}
-    for p in payloads:
-        by_key.setdefault(bucket.runner.shape_key(p), p)
-    for p in by_key.values():
-        n_pad = 1
-        while n_pad <= num_slots:
-            bucket.serve([p] * n_pad)
-            n_pad *= 2
+    # -- warm steady-state arm: every program pre-compiled
     cont = warm_engine(mk_cont(), payloads)
-    rb = run_offered_load(bucket, payloads, rate_rps=None)
     rc = run_offered_load(cont, payloads, rate_rps=None)
 
     # decode bit-identity: batched continuous == one-request-at-a-time
@@ -478,40 +350,16 @@ def continuous_rows(fast: bool = False):
     bit_identical = (len(batch_res) == len(seq_vals) and all(
         np.array_equal(r.value, v) for r, v in zip(batch_res, seq_vals)))
 
-    # mixed offered-load sweep at the same rates through both engines —
-    # the headline p99/req/s comparison
-    sweep = []
-    for mult in ((0.5, 2.0) if fast else (0.5, 1.0, 2.0, 4.0)):
-        rate = mult * rb["achieved_rps"]
-        sweep.append(dict(
-            bucket=run_offered_load(bucket, payloads, rate_rps=rate),
-            continuous=run_offered_load(cont, payloads, rate_rps=rate)))
+    sweep = [run_offered_load(cont, payloads,
+                              rate_rps=mult * rc["achieved_rps"])
+             for mult in ((0.5, 2.0) if fast else (0.5, 1.0, 2.0, 4.0))]
 
     return [dict(
         name="continuous_lm", kind="continuous", n_requests=n_req,
         prompt_lens=list(lens), horizons=list(gens), slots=num_slots,
         page_size=page_size, num_pages=num_pages,
-        # headline: the restart arm — req/s and p99 while the jit cache
-        # fills.  The bucket engine's per-(shape, padded-size) compile
-        # storm is its p99; the continuous engine's three programs are
-        # done after the first requests
-        restart_bucket_rps=restart_b["achieved_rps"],
-        restart_bucket_p99_ms=restart_b["p99_ms"],
         restart_continuous_rps=restart_c["achieved_rps"],
         restart_continuous_p99_ms=restart_c["p99_ms"],
-        restart_speedup_rps=round(restart_c["achieved_rps"]
-                                  / max(restart_b["achieved_rps"], 1e-9), 2),
-        restart_p99_improvement=round(restart_b["p99_ms"]
-                                      / max(restart_c["p99_ms"], 1e-9), 2),
-        # warm steady state.  At smoke scale on CPU the bucket engine's
-        # fused whole-generation scan amortizes host dispatch across the
-        # horizon while the continuous engine pays one host sync per
-        # decode step, so the warm crossover needs per-step compute large
-        # enough to swamp dispatch (accelerator-scale models); the
-        # structural wins that survive every scale are the bounded jit
-        # cache (restart arm) and paged KV occupancy (pool stats)
-        warm_bucket_rps=rb["achieved_rps"], warm_bucket_p50_ms=rb["p50_ms"],
-        warm_bucket_p99_ms=rb["p99_ms"],
         warm_continuous_rps=rc["achieved_rps"],
         warm_continuous_p50_ms=rc["p50_ms"],
         warm_continuous_p99_ms=rc["p99_ms"],
@@ -551,7 +399,6 @@ def serve_rows(fast: bool = False):
     if not fast:
         rows += _arch_rows("alexnet", alexnet_spec(), 112, 1, W1A8, n)
     rows += plan_rows(fast=fast)
-    rows += decode_rows(fast=fast)
     rows += throughput_rows(fast=fast)
     rows += continuous_rows(fast=fast)
     os.makedirs("results", exist_ok=True)
@@ -568,7 +415,7 @@ def main():
     enable_compile_cache()
     fast = "--fast" in sys.argv
     if "--continuous" in sys.argv:
-        # CI fast lane: only the continuous-vs-bucket comparison, with the
+        # CI fast lane: only the continuous engine's rows, with the
         # decode bit-identity gate as the exit code (a mismatch means the
         # paged path's numerics drifted from the sequential reference)
         rows = continuous_rows(fast=fast)
@@ -578,7 +425,8 @@ def main():
         print("name,us_per_call,derived")
         for r in rows:
             extra = {k: v for k, v in r.items() if k not in ("name",)}
-            print(f"{r['name']},{r['restart_speedup_rps']},{json.dumps(extra)}")
+            print(f"{r['name']},{r['warm_continuous_rps']},"
+                  f"{json.dumps(extra)}")
         print("# full rows -> results/bench_serve_continuous.json",
               file=sys.stderr)
         if not all(r["bit_identical_vs_sequential"] for r in rows):
@@ -588,10 +436,9 @@ def main():
         return
     print("name,us_per_call,derived")
     for r in serve_rows(fast=fast):
-        us = r.get("fused_us", r.get("scan_warm_us",
-                                     r.get("warm_e2e_us",
-                                           r.get("batch8_rps",
-                                                 r.get("restart_speedup_rps")))))
+        us = r.get("fused_us", r.get("warm_e2e_us",
+                                     r.get("batch8_rps",
+                                           r.get("warm_continuous_rps"))))
         extra = {k: v for k, v in r.items() if k not in ("name",)}
         print(f"{r['name']},{us},{json.dumps(extra)}")
     print("# full rows -> results/bench_serve.json", file=sys.stderr)

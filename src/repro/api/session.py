@@ -85,11 +85,14 @@ class CostReport:
 # ---------------------------------------------------------------------------
 
 class Deployment:
-    """A live serving handle over :class:`repro.launch.engine.ServeEngine`.
+    """A live serving handle over the plan's engine: a CNN plan's
+    :class:`repro.launch.engine.ServeEngine` (or its resilient subclass)
+    or an LM plan's :class:`repro.launch.engine.ContinuousLMEngine`.
 
-    Thin by design — the engine's queue/bucket/dispatch semantics are the
-    contract (DESIGN.md §7); this wrapper only ties its lifetime to the
-    compiled plan and offers the closed-loop ``predict`` convenience.
+    Thin by design — the engine's queue/dispatch semantics are the
+    contract (DESIGN.md §7, §13); this wrapper only ties its lifetime to
+    the compiled plan and offers the closed-loop ``predict`` convenience.
+    An LM's values are its generated tokens.
     """
 
     def __init__(self, engine, compiled: "CompiledModel"):
@@ -97,8 +100,22 @@ class Deployment:
         self.compiled = compiled
 
     def predict(self, payloads) -> list[np.ndarray]:
-        """Closed-loop serve: submit all payloads, drain, values in order."""
-        return [r.value for r in self.engine.serve(list(payloads))]
+        """Closed-loop serve: submit all payloads, drain, and return one
+        value per payload, in order.  Raises ``RuntimeError`` if a
+        request has no result (it was dead-lettered).  Results of requests
+        submitted earlier through :meth:`submit` are drained with them and
+        not returned."""
+        payloads = list(payloads)
+        # serve() submits in order, and both engines number requests
+        # consecutively from _next_rid
+        first = self.engine._next_rid
+        got = {r.rid: r.value for r in self.engine.serve(payloads)}
+        rids = range(first, first + len(payloads))
+        missing = [rid for rid in rids if rid not in got]
+        if missing:
+            raise RuntimeError(f"requests {missing} have no result; see "
+                               "the engine's dead_letters")
+        return [got[rid] for rid in rids]
 
     # queue-level passthroughs for open-loop drivers
     def submit(self, payload, t_submit=None) -> int:
@@ -112,8 +129,8 @@ class Deployment:
 
     def record_spans(self):
         """Turn on the engine's in-memory span recorder and return it
-        (:class:`repro.launch.engine.SpanRecorder`; the resilient engine
-        records none)."""
+        (:class:`repro.launch.engine.SpanRecorder`; the resilient CNN
+        engine records none)."""
         return self.engine.record_spans()
 
     @property
@@ -240,37 +257,71 @@ class CompiledModel:
               ) -> Deployment:
         """Stand up the request-level serving engine on this plan.
 
+        A CNN plan gets the bucket engine (``ServeEngine``); an LM plan
+        gets the continuous-batching engine (``ContinuousLMEngine``) with
+        ``max_batch`` decode slots and the engine's page geometry.  The
+        continuous engine serves on one device and has no degrade path,
+        so ``mesh`` and ``fallback`` are refused for an LM plan.
+
+        The paged KV cache holds attention layers only, so an LM whose
+        blocks are not all ``attn`` (MoE, recurrent, local-window) is
+        refused with ``PlanError``.
+
         ``resilience`` (a :class:`repro.resilience.ResilienceConfig`)
-        swaps in the fault-surviving engine: seeded fault injection,
-        crash-consistent decode epoch checkpoints, retry/dead-letter
-        recovery, and — with ``fallback`` (a lower-bit CompiledModel of
-        the same architecture) — degraded-plan fallback (DESIGN.md §11).
+        swaps in the fault-surviving engine: seeded fault injection, and
+        for a CNN plan retry/dead-letter recovery and — with ``fallback``
+        (a lower-bit CompiledModel of the same architecture) — degraded-
+        plan fallback; for an LM plan crash-consistent decode epoch
+        checkpoints (DESIGN.md §11).  A config field the plan's kind does
+        not read, set away from its default, raises ``PlanError``.
         """
         from repro.core.plan import PlanError
-        from repro.launch.engine import CNNRunner, LMRunner, ServeEngine
 
         if resilience is not None:
-            from repro.resilience import build_resilient_engine
-
-            engine = build_resilient_engine(
-                self, resilience, fallback=fallback, new_tokens=new_tokens,
-                qmode=qmode, max_batch=max_batch,
-                flush_deadline_s=flush_deadline_s, max_pending=max_pending,
-                mesh=mesh)
-            return Deployment(engine, self)
+            resilience.check_kind(self.plan.kind)
         if self.plan.kind == "lm":
+            from repro.launch.engine import ContinuousLMEngine
+
             if self.model is None:
                 raise PlanError(
                     "serving an LM plan needs its ArchConfig (cache "
                     "geometry, vocab) — reload through "
                     "api.build(cfg, ...).compile(cache=...) or "
                     "api.load(path, spec=cfg)")
-            runner = LMRunner(None, self.model.spec, new_tokens=new_tokens,
-                              qmode=qmode, model_plan=self.plan)
-        else:
-            spec = self.model.spec if self.model is not None else None
-            runner = CNNRunner(None, spec, None, plan=self.plan)
-        engine = ServeEngine(runner, max_batch=max_batch,
+            if mesh is not None or fallback is not None:
+                raise PlanError(
+                    "the continuous LM engine serves on one device and has "
+                    "no degrade path: mesh and fallback apply to CNN plans "
+                    "only")
+            kinds = sorted(set(self.model.spec.blocks_pattern) - {"attn"})
+            if kinds:
+                raise PlanError(
+                    f"the continuous LM engine's paged KV cache holds "
+                    f"attention layers only; this model has {kinds} blocks")
+            kw = {}
+            if resilience is not None:
+                kw = dict(checkpoint_dir=resilience.checkpoint_dir,
+                          epoch_steps=resilience.epoch_steps,
+                          deadline_s=resilience.deadline_s,
+                          faults=resilience.fault_plan)
+            engine = ContinuousLMEngine(
+                None, self.model.spec, num_slots=max_batch,
+                new_tokens=new_tokens, qmode=qmode, model_plan=self.plan,
+                max_pending=max_pending, **kw)
+            return Deployment(engine, self)
+        if resilience is not None:
+            from repro.resilience import build_resilient_engine
+
+            engine = build_resilient_engine(
+                self, resilience, fallback=fallback, max_batch=max_batch,
+                flush_deadline_s=flush_deadline_s, max_pending=max_pending,
+                mesh=mesh)
+            return Deployment(engine, self)
+        from repro.launch.engine import CNNRunner, ServeEngine
+
+        spec = self.model.spec if self.model is not None else None
+        engine = ServeEngine(CNNRunner(None, spec, None, plan=self.plan),
+                             max_batch=max_batch,
                              flush_deadline_s=flush_deadline_s, mesh=mesh,
                              max_pending=max_pending)
         return Deployment(engine, self)

@@ -4,7 +4,7 @@ The paper's resilience argument (arxiv 1904.07864 §IV) is that forward
 progress survives power loss when state is retained at *fine granularity*;
 the serving analogue is KV state held in fixed-size pages that requests
 acquire on admission and release on retirement — no contiguous re-padding
-(``launch/serve.grow_cache``) and no defragmentation, ever.  A request's
+and no defragmentation, ever.  A request's
 KV occupancy is a *page table* (an ordered list of page indices); freeing
 is O(pages) list surgery, and a freed page is reusable immediately because
 the device-side position buffer (``ppos``) is reset to -1 at the next
@@ -42,7 +42,7 @@ class PagePool:
 
     FIFO reuse (freed pages re-allocate in release order) keeps the
     allocation sequence a pure function of the request schedule — the
-    deterministic-replay property the resilience checkpoints rely on.
+    deterministic-replay property the engine's epoch checkpoints rely on.
     """
 
     def __init__(self, num_pages: int, page_size: int):

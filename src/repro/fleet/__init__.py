@@ -10,7 +10,7 @@ in the serve stack, lazily.
 from .search import (REFERENCE_ERROR_PCT, SLO_LEVELS, assign_slos,
                      candidate_space, codesign, frame_cost_table,
                      load_accuracy_table)
-from .sim import (NodeConfig, epoch_schedule, fleet_report, live_validation,
+from .sim import (NodeConfig, fleet_report, live_validation,
                   measured_efficiency, outage_faultplan,
                   predict_engine_stats, rescale_outages, simulate_fleet,
                   simulate_node)
@@ -20,7 +20,7 @@ from .traces import (ARCHETYPES, DAY_S, DEFAULT_MIX, HarvestTrace, TraceSpec,
 __all__ = [
     "ARCHETYPES", "DAY_S", "DEFAULT_MIX", "HarvestTrace", "NodeConfig",
     "REFERENCE_ERROR_PCT", "SLO_LEVELS", "TraceSpec", "assign_slos",
-    "candidate_space", "codesign", "epoch_schedule", "fleet_report",
+    "candidate_space", "codesign", "fleet_report",
     "frame_cost_table", "generate_fleet", "live_validation",
     "load_accuracy_table", "make_trace", "measured_efficiency",
     "outage_faultplan", "predict_engine_stats", "rescale_outages",

@@ -20,8 +20,9 @@ Two arms, one failure model:
 * **discrete arm** (:func:`predict_engine_stats` + :func:`live_validation`)
   — the fluid arm's derived outage instants become a
   ``FaultPlan.timeline`` (power_loss at fixed work-clock times), which is
-  polled by BOTH a step-exact mirror of ``ResilientServeEngine``'s hook
-  sequence and the real engine.  Simulated outages and live-engine chaos
+  polled by BOTH a step-exact mirror of the continuous LM engine's hook
+  sequence (``launch/engine.ContinuousLMEngine``) and the real engine,
+  built by the resilient facade.  Simulated outages and live-engine chaos
   share one failure model by construction, and the validation contract is
   stated in :func:`live_validation`: integer work counters match exactly,
   float accounting within ``tol``.
@@ -32,20 +33,24 @@ in this package, same as ``resilience/``.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
-from collections import deque
 
 import numpy as np
 
-from repro.resilience.faults import (DEVICE_DROP, POWER_LOSS, SLOW_DISPATCH,
-                                     STAGING_CORRUPTION, FaultPlan)
+from repro.resilience.faults import DEVICE_DROP, POWER_LOSS, FaultPlan
 from .traces import DAY_S, HarvestTrace
 
-# Mirror of the engine's non-decode hook charges (resilience/engine.py).
-# Defined locally so the fluid simulator imports without jax; a unit test
-# pins these against the engine's own constants.
-STAGING_DT = 0.25
+# Mirror of the continuous engine's hook charges and of the page geometry
+# the facade gives it (launch/engine.ContinuousLMEngine defaults): one
+# work-clock unit per prefill chunk and per decode step, chunks of one
+# 16-token page, a pool of 64 pages.  Defined locally so the fluid
+# simulator imports without jax; a unit test pins them against a live
+# engine's fault clock.
 PREFILL_DT = 1.0
+DECODE_DT = 1.0
+PAGE_SIZE = 16
+NUM_PAGES = 64
 
 _EPS = 1e-12
 
@@ -348,10 +353,10 @@ def fleet_report(results, specs=None) -> dict:
 
 def outage_faultplan(outage_frames) -> FaultPlan:
     """A node's derived outage schedule as a live fault plan: power_loss
-    at fixed work-clock instants (frames ~ logical decode steps).  The
+    at fixed work-clock instants (frames ~ logical dispatches).  The
     same JSON spec drives :func:`predict_engine_stats` and a real
-    :class:`~repro.resilience.engine.ResilientServeEngine` — one failure
-    model for simulated and live arms."""
+    continuous LM engine — one failure model for simulated and live
+    arms."""
     return FaultPlan.timeline([(t, POWER_LOSS) for t in outage_frames])
 
 
@@ -369,106 +374,139 @@ def rescale_outages(outage_frames, node_work_frames: float,
     return [t * k for t in outage_frames]
 
 
-def epoch_schedule(new_tokens: int, epoch_steps: int) -> tuple:
-    """Mirror of ``EpochLMRunner.epoch_schedule``."""
-    n, k = new_tokens - 1, epoch_steps
-    return tuple([k] * (n // k) + ([n % k] if n % k else []))
+class _Kill(Exception):
+    """A kill-class fault in the mirror: the node reboots."""
+
+
+def _replay(faults: FaultPlan, *, n_requests: int, new_tokens: int,
+            epoch_steps: int, max_batch: int, prompt_len: int) -> dict:
+    """One pass of the mirror: ``ContinuousLMEngine.serve`` of
+    ``n_requests`` equal requests, step for step (see
+    :func:`predict_engine_stats`)."""
+    chunks = -(-prompt_len // PAGE_SIZE)
+    need = -(-(prompt_len + new_tokens) // PAGE_SIZE)
+    s = dict(steps=0, prefill_chunks=0, dispatches=0, commits=0,
+             power_losses=0, admissions=0, retirements=0, requests=0,
+             wasted_steps=0.0)
+    # the volatile schedule: decode step, step of the last commit, free
+    # pages, waiting requests, tokens emitted per slot (None = free slot),
+    # finished results
+    start = dict(step=0, last=None, free=NUM_PAGES, waiting=n_requests,
+                 slots=[None] * max_batch, done=0)
+    state = copy.deepcopy(start)
+    committed = None
+
+    def poll(site: str, dt: float) -> None:
+        ev = faults.poll(site, dt=dt)
+        if ev is not None and ev.kind in (POWER_LOSS, DEVICE_DROP):
+            s["wasted_steps"] += ev.offset
+            raise _Kill
+
+    def retire(i: int) -> None:
+        state["slots"][i] = None
+        state["free"] += need
+        state["done"] += 1
+        s["retirements"] += 1
+        s["requests"] += 1
+
+    while state["waiting"] or any(e is not None for e in state["slots"]):
+        try:
+            # _admit: FIFO while a slot is free and the pages fit
+            while (state["waiting"] and None in state["slots"]
+                   and state["free"] >= need):
+                i = state["slots"].index(None)
+                state["waiting"] -= 1
+                state["free"] -= need
+                state["slots"][i] = 0
+                s["admissions"] += 1
+                for _ in range(chunks):               # _prefill
+                    poll("prefill", PREFILL_DT)
+                    s["prefill_chunks"] += 1
+                    s["dispatches"] += 1
+                state["slots"][i] = 1
+                if new_tokens <= 1:
+                    retire(i)
+            # _maybe_commit: before the step, every epoch_steps steps
+            if (state["last"] is None
+                    or state["step"] - state["last"] >= epoch_steps):
+                state["last"] = state["step"]
+                committed = copy.deepcopy(state)
+                s["commits"] += 1
+            # _decode_step
+            active = [i for i, e in enumerate(state["slots"]) if e is not None]
+            if active:
+                poll("decode", DECODE_DT)
+                s["dispatches"] += 1
+                s["steps"] += 1
+                state["step"] += 1
+                for i in active:
+                    state["slots"][i] += 1
+                    if state["slots"][i] >= new_tokens:
+                        retire(i)
+        except _Kill:
+            # _reboot: everything volatile is gone; resume from the last
+            # commit, or cold with every request enqueued again without
+            # one (all were submitted before the first pump, so a commit
+            # holds each one not yet answered)
+            s["power_losses"] += 1
+            state = copy.deepcopy(committed if committed is not None
+                                  else start)
+    s["completed"] = state["done"]
+    return s
 
 
 def predict_engine_stats(fault_spec, *, n_requests: int, new_tokens: int,
-                         epoch_steps: int, max_batch: int) -> dict:
-    """The simulator's accounting of what ``ResilientServeEngine`` will do
-    under ``fault_spec`` (a ``FaultPlan`` JSON spec or instance).
+                         epoch_steps: int, max_batch: int,
+                         prompt_len: int = 8) -> dict:
+    """The simulator's accounting of what the continuous LM engine will do
+    under ``fault_spec`` (a ``FaultPlan`` JSON spec or instance) when it
+    serves ``n_requests`` prompts of ``prompt_len`` tokens, ``new_tokens``
+    each, on ``max_batch`` slots with epoch checkpoints, in the facade's
+    page geometry.
 
-    A step-exact mirror of the engine's hook sequence with checkpointing
-    on: per attempt — staging poll (dt 0.25); prefill poll (dt 1.0) only
-    when no checkpoint exists yet, commit after prefill; one decode poll
-    per epoch (dt = steps), commit after each; a kill-class event requeues
-    the bucket FIFO keeping its committed epoch.  Polls the same
+    A step-exact mirror of ``ContinuousLMEngine``'s hook sequence: each
+    ``pump`` admits FIFO into free slots, polling ``prefill`` (dt 1.0) per
+    prompt chunk; commits when ``epoch_steps`` decode steps have passed
+    since the last commit (and on the first pump); then polls ``decode``
+    (dt 1.0) and runs one step.  A kill-class event reboots the node and
+    resumes from the last commit — work after it is redone, and without a
+    commit every request starts again from the queue.  Polls the same
     ``FaultPlan`` implementation the engine does, so fault times and
-    offsets agree bit-for-bit.  Assumes no dead-letters (the validation
-    arm runs the engine with a huge ``max_retries``) and no degrade
-    (energy scale 1)."""
+    offsets agree bit-for-bit.  Assumes a closed-loop ``serve`` (every
+    request submitted before the first pump) and no deadlines.
+
+    Counters are the engine's own ``stats`` keys (``steps``,
+    ``prefill_chunks``, ``dispatches``, ``commits``, ``power_losses``,
+    ``admissions``, ``retirements``, ``requests``), plus ``wasted_steps``
+    (the partial windows the kills cut short, from the fault log),
+    ``completed`` (results at the end) and ``useful_dispatches`` (the
+    dispatches of the same replay without faults)."""
     faults = (fault_spec if isinstance(fault_spec, FaultPlan)
               else FaultPlan.from_json(fault_spec))
-    schedule = epoch_schedule(new_tokens, epoch_steps)
-    sizes = [max_batch] * (n_requests // max_batch)
-    if n_requests % max_batch:
-        sizes.append(n_requests % max_batch)
-    # bucket state: [n_requests, committed_epoch or None (no checkpoint)]
-    queue = deque([size, None] for size in sizes)
-    s = dict(faults=0, power_losses=0, device_drops=0, slow_dispatches=0,
-             staging_retries=0, retries=0, prefills=0, resumes=0, epochs=0,
-             commits=0, executed_steps=0, useful_steps=0, wasted_steps=0.0,
-             dispatches=0, requests=0)
-
-    def _kill(ev, bucket, charge_offset: bool) -> bool:
-        if ev is None:
-            return False
-        if ev.kind == SLOW_DISPATCH:
-            s["slow_dispatches"] += 1
-            return False
-        if ev.kind == STAGING_CORRUPTION:
-            s["staging_retries"] += 1
-            return False
-        s["faults"] += 1
-        s["power_losses" if ev.kind == POWER_LOSS else "device_drops"] += 1
-        if charge_offset:
-            # only _fault_gate (prefill/decode) charges the partial window;
-            # a staging kill raises from _stage_checked without it
-            s["wasted_steps"] += ev.offset
-        s["retries"] += bucket[0]
-        queue.append(bucket)
-        return True
-
-    while queue:
-        bucket = queue.popleft()
-        if _kill(faults.poll("staging", dt=STAGING_DT), bucket,
-                 charge_offset=False):
-            continue
-        if bucket[1] is None:
-            if _kill(faults.poll("prefill", dt=PREFILL_DT), bucket,
-                     charge_offset=True):
-                continue
-            s["prefills"] += 1
-            s["commits"] += 1          # the epoch-0 (post-prefill) commit
-            bucket[1] = 0
-        else:
-            s["resumes"] += 1
-        killed = False
-        for e in range(bucket[1], len(schedule)):
-            steps = schedule[e]
-            if _kill(faults.poll("decode", dt=float(steps)), bucket,
-                     charge_offset=True):
-                killed = True
-                break
-            s["executed_steps"] += steps
-            s["epochs"] += 1
-            s["commits"] += 1
-            bucket[1] = e + 1
-        if killed:
-            continue
-        s["useful_steps"] += sum(schedule)
-        s["dispatches"] += 1
-        s["requests"] += bucket[0]
+    kw = dict(n_requests=n_requests, new_tokens=new_tokens,
+              epoch_steps=epoch_steps, max_batch=max_batch,
+              prompt_len=prompt_len)
+    s = _replay(faults, **kw)
+    s["useful_dispatches"] = _replay(FaultPlan(None), **kw)["dispatches"]
     return s
 
 
 def measured_efficiency(stats, nv_write_steps: float = 0.0) -> float:
-    """Useful steps over total charged work — the same formula
-    ``benchmarks/bench_resilience`` applies to live engine stats, usable
-    on :func:`predict_engine_stats` output interchangeably."""
-    restarts = max(0.0, stats["prefills"] + stats["resumes"]
-                   - stats["dispatches"])
-    total = (stats["executed_steps"] + stats["wasted_steps"] + restarts
+    """Useful work over total charged work, in dispatch units: the
+    fault-free run's dispatches over the dispatches executed (redone ones
+    included), the partial windows the kills cut short, and
+    ``nv_write_steps`` per commit.  Applies to :func:`predict_engine_stats`
+    output and to live engine stats carrying the same keys, for a run in
+    which every request completes."""
+    total = (stats["dispatches"] + stats["wasted_steps"]
              + nv_write_steps * stats["commits"])
-    return stats["useful_steps"] / total if total else 0.0
+    return stats["useful_dispatches"] / total if total else 0.0
 
 
 # keys whose exact/tolerance match constitutes the validation contract
-_VALIDATE_INT_KEYS = ("faults", "power_losses", "prefills", "resumes",
-                      "epochs", "commits", "executed_steps", "useful_steps",
-                      "dispatches", "requests", "retries")
+_VALIDATE_INT_KEYS = ("power_losses", "commits", "steps", "prefill_chunks",
+                      "dispatches", "admissions", "retirements", "requests",
+                      "completed", "useful_dispatches")
 _VALIDATE_FLOAT_KEYS = ("wasted_steps",)
 
 
@@ -476,22 +514,26 @@ def live_validation(outage_frames, *, checkpoint_dir, n_requests: int = 8,
                     new_tokens: int = 7, epoch_steps: int = 2,
                     max_batch: int = 4, prompt_len: int = 8,
                     tol: float = 1e-6) -> dict:
-    """Replay one node's outage schedule through a REAL
-    ``ResilientServeEngine`` (tiny smoke LM) and compare its measured
-    stats against :func:`predict_engine_stats` on the same fault spec.
+    """Replay one node's outage schedule through a REAL continuous LM
+    engine (tiny smoke LM, built by ``compiled.serve(resilience=...)``)
+    and compare its measured stats against :func:`predict_engine_stats`
+    on the same fault spec.
 
     Validation contract (the "stated tolerance" of the acceptance
     criteria): every integer work counter in ``_VALIDATE_INT_KEYS``
     matches EXACTLY; float accounting (``wasted_steps`` and the derived
-    ``measured_efficiency``) matches within ``tol`` (absolute).  Both
-    arms poll the same ``FaultPlan.timeline`` JSON spec — one failure
-    model, two executors.
+    ``measured_efficiency``) matches within ``tol`` (absolute); every
+    request completes, with no dead letter, and its tokens equal the
+    fault-free run's.  Both arms poll
+    the same ``FaultPlan.timeline`` JSON spec — one failure model, two
+    executors.
     """
     import jax                                    # noqa: F401 (lazy; the
-    from repro.configs import SINGLE, all_configs  # fluid arm needs no jax)
+    from repro import api                         # fluid arm needs no jax)
+    from repro.configs import SINGLE, all_configs
     from repro.core.quant import PAPER_CONFIGS
     from repro.models import transformer as T
-    from repro.resilience import EpochLMRunner, ResilientServeEngine
+    from repro.resilience import ResilienceConfig
 
     spec = outage_faultplan(outage_frames).to_json()
     cfg = dataclasses.replace(
@@ -500,22 +542,34 @@ def live_validation(outage_frames, *, checkpoint_dir, n_requests: int = 8,
             vocab=64, head_dim=32),
         quant=PAPER_CONFIGS["w1a8"])
     params, _ = T.init_lm(jax.random.PRNGKey(0), cfg, SINGLE)
+    compiled = api.build(cfg, params=params).compile(
+        batch_hints=(1, max_batch), prompt_len=prompt_len)
     prompts = [np.random.RandomState(i).randint(0, 64, size=(prompt_len,))
                .astype(np.int32) for i in range(n_requests)]
-    runner = EpochLMRunner(params, cfg, new_tokens=new_tokens,
-                           epoch_steps=epoch_steps)
-    eng = ResilientServeEngine(runner, fault_plan=FaultPlan.from_json(spec),
-                               checkpoint_dir=checkpoint_dir,
-                               max_batch=max_batch, max_retries=10**9)
+    kw = dict(max_batch=max_batch, new_tokens=new_tokens)
+    ref_eng = compiled.serve(**kw).engine
+    ref = {r.rid: r.value for r in ref_eng.serve(prompts)}
+    fault_plan = FaultPlan.from_json(spec)
+    eng = compiled.serve(resilience=ResilienceConfig(
+        fault_plan=fault_plan, checkpoint_dir=checkpoint_dir,
+        epoch_steps=epoch_steps), **kw).engine
     results = eng.serve(prompts)
     predicted = predict_engine_stats(spec, n_requests=n_requests,
                                      new_tokens=new_tokens,
                                      epoch_steps=epoch_steps,
-                                     max_batch=max_batch)
-    measured = {k: eng.stats[k] for k in (*_VALIDATE_INT_KEYS,
-                                          *_VALIDATE_FLOAT_KEYS)}
+                                     max_batch=max_batch,
+                                     prompt_len=prompt_len)
+    measured = {k: eng.stats[k] for k in _VALIDATE_INT_KEYS
+                if k in eng.stats}
+    measured.update(
+        completed=len(results),
+        useful_dispatches=ref_eng.stats["dispatches"],
+        wasted_steps=sum(ev.offset for ev in fault_plan.log
+                         if ev.kind in (POWER_LOSS, DEVICE_DROP)))
+    identical = all(np.array_equal(r.value, ref[r.rid]) for r in results)
     deltas = {}
-    ok = len(results) == n_requests and not eng.dead_letters
+    ok = (len(results) == n_requests and identical
+          and not eng.dead_letters)
     for k in _VALIDATE_INT_KEYS:
         deltas[k] = int(measured[k]) - int(predicted[k])
         ok = ok and deltas[k] == 0
@@ -529,9 +583,5 @@ def live_validation(outage_frames, *, checkpoint_dir, n_requests: int = 8,
     return dict(ok=bool(ok), tol=tol, fault_spec=spec, predicted=predicted,
                 measured=measured, deltas=deltas,
                 efficiency_predicted=eff_pred, efficiency_measured=eff_meas,
-                completed=len(results), dead_letters=len(eng.dead_letters))
-
-
-# DEVICE_DROP is imported for _kill's kind split but never drawn by
-# timeline plans; referenced here so the shared-model contract is explicit
-_KILL_KINDS = (POWER_LOSS, DEVICE_DROP)
+                bit_identical=identical, completed=len(results),
+                dead_letters=len(eng.dead_letters))

@@ -1,16 +1,18 @@
-"""Request-level serving engine: queue -> padding buckets -> device dispatch.
+"""Request-level serving engines: :class:`ServeEngine` batches CNN
+requests (queue -> padding buckets -> device dispatch) and
+:class:`ContinuousLMEngine` serves LMs by continuous batching over a
+paged KV cache (DESIGN.md §7, §13).
 
-PRs 1-2 made a *single* request fast (fused qGEMM, implicit-GEMM conv,
-scanned decode); this engine turns that fast single-shot path into a loaded
-multi-request, multi-device system (DESIGN.md §7):
+The CNN engine turns the fast single-image forward (fused qGEMM,
+implicit-GEMM conv) into a loaded multi-request, multi-device system:
 
   * **Request queue + padding-bucket batcher** — independent requests are
-    grouped by shape key (prompt length for LMs, image shape for CNNs) and
-    coalesced into one device dispatch.  A bucket flushes when it reaches
-    ``max_batch`` or when its oldest request has waited ``flush_deadline_s``
-    (latency bound under light load).  Ragged flushes pad the batch up to
-    the next power of two (and to a device-count multiple), so the jit
-    cache holds at most log2(max_batch)+1 programs per shape key.
+    grouped by shape key (image shape) and coalesced into one device
+    dispatch.  A bucket flushes when it reaches ``max_batch`` or when its
+    oldest request has waited ``flush_deadline_s`` (latency bound under
+    light load).  Ragged flushes pad the batch up to the next power of
+    two (and to a device-count multiple), so the jit cache holds at most
+    log2(max_batch)+1 programs per shape key.
   * **Double-buffered host->device staging** — while bucket *i* computes,
     bucket *i+1*'s arrays transfer and bucket *i-1*'s results harvest; at
     most two buckets are in flight on device (bounded memory; the rest of
@@ -27,11 +29,11 @@ multi-request, multi-device system (DESIGN.md §7):
     parallel devices.  With one device the engine falls back to plain
     ``jit`` (no collective machinery).
 
-Correctness contract: batching is invisible.  The serve forwards are
-per-sample independent (per-sample norm statistics, per-request KV cache
-rows), so a request's result is bit-identical whether it ran alone, in a
-full bucket, in a ragged padded bucket, or sharded across devices —
-``tests/test_engine.py`` pins this across engines and bucket shapes.
+Correctness contract: batching is invisible.  The serve forward is
+per-sample independent (per-sample norm statistics), so a request's
+result is bit-identical whether it ran alone, in a full bucket, in a
+ragged padded bucket, or sharded across devices — ``tests/test_engine.py``
+pins this across engines and bucket shapes.
 """
 from __future__ import annotations
 
@@ -244,86 +246,6 @@ class CNNRunner:
     split = staticmethod(_split_rows)
 
 
-class LMRunner:
-    """Batched LM generate (tokens (S_p,) -> generated tokens (S_d,)).
-
-    One device program per (prompt-len, horizon) bucket shape: jitted
-    prefill + cache growth + the one-trace ``lax.scan`` greedy decode of
-    ``launch/serve.py``, fused into a single dispatch per bucket.
-
-    Payloads are either a plain token array (horizon = the runner-level
-    ``new_tokens`` default) or a ``(tokens, new_tokens)`` tuple for
-    per-request horizons — mixed horizons land in distinct buckets (the
-    shape key includes the horizon), which is exactly the fragmentation
-    the continuous engine exists to remove.
-    """
-
-    def __init__(self, params, cfg, *, new_tokens: int, qmode: str = "serve",
-                 plan=None, model_plan=None):
-        from repro.configs import SINGLE
-
-        self.model_plan = model_plan  # compiled ModelPlan (core/plan.py)
-        self.params = model_plan.params if model_plan is not None else params
-        self.cfg = cfg
-        self.new_tokens = new_tokens
-        self.qmode = qmode
-        self.plan = plan or SINGLE    # sharding plan (configs.SINGLE-style)
-
-    def plan_fingerprint(self):
-        return (None if self.model_plan is None
-                else self.model_plan.fingerprint())
-
-    @staticmethod
-    def split_payload(payload) -> tuple:
-        """Normalize a payload to ``(tokens, new_tokens | None)``."""
-        if isinstance(payload, tuple):
-            toks, nt = payload
-            return np.asarray(toks, np.int32), int(nt)
-        return np.asarray(payload, np.int32), None
-
-    def shape_key(self, payload) -> tuple:
-        toks, nt = self.split_payload(payload)
-        return ("lm", int(toks.shape[-1]),
-                self.new_tokens if nt is None else nt)
-
-    def collate(self, payloads, pad_to: int) -> np.ndarray:
-        return _collate([self.split_payload(p)[0] for p in payloads],
-                        pad_to, np.int32)
-
-    def make_forward(self, key) -> Callable:
-        import contextlib
-
-        from repro.launch.serve import (greedy_token, grow_cache,
-                                        make_decode_step)
-        from repro.models import transformer as T
-
-        _, prompt_len, new_tokens = key
-        cfg, plan, qmode = self.cfg, self.plan, self.qmode
-        model_plan = self.model_plan
-        slots = prompt_len + new_tokens
-
-        def fwd(params, toks):
-            # activate() covers jit TRACE time: projection GEMMs dispatch
-            # through the plan's dense verdict table; the compiled program
-            # keeps those engines for its lifetime
-            ctx = (model_plan.activate() if model_plan is not None
-                   else contextlib.nullcontext())
-            with ctx:
-                logits, cache = T.prefill(params, cfg, plan, tokens=toks,
-                                          qmode=qmode)
-                cache = grow_cache(cache, prompt_len, slots)
-                first = greedy_token(logits, cfg.vocab)
-                step = make_decode_step(params, cfg, plan, qmode)
-                (_, _, _), toks_out = jax.lax.scan(
-                    step, (cache, first, jnp.asarray(prompt_len, jnp.int32)),
-                    None, length=new_tokens - 1)
-                return jnp.concatenate([first, toks_out[:, :, 0].T], axis=1)
-
-        return fwd
-
-    split = staticmethod(_split_rows)
-
-
 # ---------------------------------------------------------------------------
 # Engine
 # ---------------------------------------------------------------------------
@@ -393,7 +315,7 @@ class ServeEngine(_SubmitRetryMixin):
 
     Parameters
     ----------
-    runner:           a :class:`CNNRunner`/:class:`LMRunner`-shaped adapter.
+    runner:           a :class:`CNNRunner`-shaped adapter.
     max_batch:        bucket capacity = the largest dispatched batch.
     flush_deadline_s: max queueing delay before a partial bucket flushes.
     mesh:             1-D ``("data",)`` mesh (``launch/mesh.make_serve_mesh``)
@@ -658,12 +580,11 @@ class _Slot:
 class ContinuousLMEngine(_SubmitRetryMixin):
     """Step-granular continuous batching over a paged KV cache.
 
-    The bucket engine (:class:`ServeEngine` + :class:`LMRunner`) closes a
-    batch at dispatch: every co-batched request shares one (prompt-len,
-    horizon) shape, runs its full scan, and the batch retires together —
-    mixed lengths fragment into many small dispatches and short requests
-    wait on long ones (head-of-line blocking).  This engine keeps ONE
-    persistent in-flight batch of ``num_slots`` decode slots instead:
+    The one LM serving engine (``CompiledModel.serve()`` on an LM plan
+    builds it).  A bucket batch would close at dispatch, so mixed lengths
+    would fragment into many small dispatches and short requests would
+    wait on long ones.  This engine keeps ONE persistent in-flight batch
+    of ``num_slots`` decode slots instead:
 
     * **Admission at step granularity** — a waiting request joins any free
       slot between decode steps.  Its KV pages (the full extent,
@@ -675,8 +596,7 @@ class ContinuousLMEngine(_SubmitRetryMixin):
       the bit-identity and resume tests replay.
     * **Chunked prefill insert** — the prompt streams into its pages in
       fixed ``chunk``-token pieces at batch 1 (table sliced to the
-      admitting slot).  No bucket re-open, no contiguous re-padding:
-      ``launch/serve.grow_cache`` (ne ``widen_cache``) has no role here.
+      admitting slot).  No bucket re-open, no contiguous re-padding.
     * **Mid-flight retirement** — a slot that reaches its horizon retires
       between steps, frees its pages (FIFO reuse), and its slot admits the
       next waiting request.  Requests with different horizons coexist in
@@ -697,8 +617,11 @@ class ContinuousLMEngine(_SubmitRetryMixin):
       allocator free list, slot metadata, waiting queue, finished
       results).  A :class:`~repro.resilience.faults.PowerLoss` /
       ``DeviceDrop`` polled from ``faults`` wipes volatile state and
-      resumes from the last commit; determinism of the schedule makes the
-      resumed run bit-identical to an uninterrupted one.
+      resumes from the last commit (cold without one).  The host's record
+      of submitted requests outlives the kill: each request the restored
+      schedule does not hold is enqueued again under its rid, so every
+      submitted request is answered exactly once, bit-identical to an
+      uninterrupted run.
 
     Correctness contract: per-slot numerics are independent of batchmates.
     The constructor forces ``act_scale_mode="row"`` for quantized serve
@@ -722,6 +645,8 @@ class ContinuousLMEngine(_SubmitRetryMixin):
 
         if num_slots < 1:
             raise ValueError(f"need at least one slot, got {num_slots}")
+        if epoch_steps < 1:
+            raise ValueError(f"epoch_steps must be >= 1, got {epoch_steps}")
         self.model_plan = model_plan
         params = model_plan.params if model_plan is not None else params
         quant = cfg.quant
@@ -760,6 +685,10 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         self._slots: list = [None] * num_slots
         self._waiting: deque[_Pending] = deque()
         self._results: dict[int, Result] = {}
+        # every submitted request not yet handed back (by drain or as a
+        # dead letter): rid -> its payload, None for a result restored
+        # from disk.  Host memory, so it survives a kill (_reboot)
+        self._outstanding: dict[int, _Pending | None] = {}
         self.dead_letters: list[dict] = []
         self._next_rid = 0
         self._step = 0              # decode steps executed (the work clock)
@@ -772,14 +701,17 @@ class ContinuousLMEngine(_SubmitRetryMixin):
                           dead_lettered=0, commits=0, power_losses=0)
         self.spans: SpanRecorder | None = None
 
-        self.epoch_steps = max(int(epoch_steps), 1)
+        self.epoch_steps = int(epoch_steps)
         self._last_commit: int | None = None
         self.ckpt = None
         if checkpoint_dir is not None:
             from repro.train.checkpoint import Checkpointer
             self.ckpt = Checkpointer(checkpoint_dir, keep=2,
                                      async_save=False)
-            self._try_restore()  # resume a prior engine's in-flight state
+            if self._try_restore():  # resume a prior engine's work
+                self._outstanding = dict.fromkeys(self._results)
+                self._outstanding.update(
+                    (p.rid, p) for p in self._held_requests())
 
     def record_spans(self) -> SpanRecorder:
         """Turn on the span recorder (off by default) and return it:
@@ -869,9 +801,9 @@ class ContinuousLMEngine(_SubmitRetryMixin):
     # -- queue side ----------------------------------------------------------
 
     def _normalize(self, payload) -> tuple:
-        toks, nt = LMRunner.split_payload(payload)
-        toks = np.atleast_1d(toks).reshape(-1)
-        return toks, (self.new_tokens if nt is None else nt)
+        toks, nt = payload if isinstance(payload, tuple) else (payload, None)
+        toks = np.atleast_1d(np.asarray(toks, np.int32)).reshape(-1)
+        return toks, (self.new_tokens if nt is None else int(nt))
 
     def submit(self, payload, t_submit: float | None = None) -> int:
         """Enqueue one request (token array, or ``(tokens, new_tokens)``);
@@ -896,8 +828,9 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         rid = self._next_rid
         self._next_rid += 1
         now = self.clock()
-        self._waiting.append(
-            _Pending(rid, toks, nt, now if t_submit is None else t_submit))
+        req = _Pending(rid, toks, nt, now if t_submit is None else t_submit)
+        self._waiting.append(req)
+        self._outstanding[rid] = req
         return rid
 
     # -- scheduler -----------------------------------------------------------
@@ -1040,6 +973,7 @@ class ContinuousLMEngine(_SubmitRetryMixin):
                 self.dead_letters.append(dict(
                     rid=s.rid, t_submit=s.t_submit,
                     emitted=list(s.emitted), reason="deadline"))
+                self._outstanding.pop(s.rid, None)
                 self.stats["dead_lettered"] += 1
 
     # -- engine loop ---------------------------------------------------------
@@ -1066,6 +1000,8 @@ class ContinuousLMEngine(_SubmitRetryMixin):
             self.pump()
         out = [self._results[rid] for rid in sorted(self._results)]
         self._results.clear()
+        for r in out:
+            self._outstanding.pop(r.rid, None)
         return out
 
     def serve(self, payloads) -> list[Result]:
@@ -1157,14 +1093,27 @@ class ContinuousLMEngine(_SubmitRetryMixin):
         self._last_commit = self._step
         return True
 
+    def _held_requests(self):
+        """The schedule's unfinished requests as payloads: slots, then
+        the waiting queue."""
+        for s in self._slots:
+            if s is not None:
+                yield _Pending(s.rid, s.tokens, s.new_tokens, s.t_submit)
+        yield from self._waiting
+
     def _reboot(self) -> None:
         """Power came back: everything volatile (device pools, host
         schedule) is gone.  Re-init cold, then resume from the last epoch
-        commit if there is one — requests admitted or submitted after it
-        are lost, exactly like a real brownout."""
+        commit if there is one.  The record of submitted requests, the
+        rid counter and the dead letters are host state the kill leaves:
+        restored work already handed back is dropped, and every request
+        still owed that the restored schedule does not hold (submitted or
+        admitted after the commit; all of them without one) is enqueued
+        again FIFO under its rid."""
         from repro.core.kv_pages import PagePool
         from repro.models import transformer as T
 
+        next_rid, dead = self._next_rid, self.dead_letters
         cache = T.init_paged_cache(self.cfg, self.plan, self.num_slots,
                                    self.pool.num_pages, self.page_size,
                                    self.table_pages)
@@ -1181,6 +1130,19 @@ class ContinuousLMEngine(_SubmitRetryMixin):
             self.spans.abandon()
         if self.ckpt is not None:
             self._try_restore()
+        self._next_rid, self.dead_letters = next_rid, dead
+        owed = self._outstanding
+        for i, s in enumerate(self._slots):
+            if s is not None and s.rid not in owed:
+                self._slots[i] = None
+                self.pool.free(s.pages)
+                self._table[i, :] = self.pool.null_page
+        self._waiting = deque(p for p in self._waiting if p.rid in owed)
+        self._results = {r: v for r, v in self._results.items()
+                         if r in owed}
+        held = {p.rid for p in self._held_requests()} | set(self._results)
+        self._waiting.extend(owed[r] for r in sorted(owed)
+                             if r not in held and owed[r] is not None)
 
 
 # ---------------------------------------------------------------------------

@@ -4,10 +4,11 @@ The paper's low bit-width operating points (W1A1 .. W1A8, Table/Fig. 5-6)
 are not just an accuracy/energy dial — under intermittent power they are a
 *survival* dial: a lower-bit plan moves fewer bytes and burns fewer pJ per
 dispatch, so the same harvested-energy envelope completes more frames.
-:class:`DegradePolicy` decides *when* the serving engine should take that
-trade; :class:`repro.resilience.engine.ResilientServeEngine` executes it by
-swapping to the next pre-compiled fallback ``ModelPlan`` (plans reload in
-~26 ms, so the swap is cheap and deterministic).
+:class:`DegradePolicy` decides *when* the CNN serving engine should take
+that trade; :class:`repro.resilience.engine.ResilientServeEngine` executes
+it by swapping to the next pre-compiled fallback ``ModelPlan`` (plans
+reload in ~26 ms, so the swap is cheap and deterministic).  The continuous
+LM engine has no degrade path.
 
 Two triggers, either sufficient:
 

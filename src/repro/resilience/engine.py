@@ -1,15 +1,12 @@
-"""Fault-surviving serve engine: epoch decode, recovery, degradation.
+"""Fault-surviving CNN serve engine: recovery and degradation.
 
 ``ServeEngine`` (launch/engine.py) made many requests fast; this subclass
 makes them survive the paper's operating environment — a power-intermittent
 node (§II-B3) — without giving up the bit-identity contract:
 
-* every dispatch is bracketed by :class:`repro.resilience.faults.FaultPlan`
-  hook points (staging, prefill, per decode epoch, single-shot dispatch);
-* the LM decode runs as K-step **epochs** (:class:`EpochLMRunner`) whose
-  state commits through :class:`~repro.resilience.checkpoints.
-  DecodeCheckpointer` after every epoch — the software NV-FA: a kill
-  mid-decode loses at most one epoch, never the prefill or prior tokens;
+* every bucket is bracketed by :class:`repro.resilience.faults.FaultPlan`
+  hook points (staging, then the single-shot dispatch); a CNN bucket is
+  one program, so there is no partial state to checkpoint;
 * a killed bucket's requests are **re-enqueued idempotently** (same rid,
   same ``t_submit``, results recorded at most once) behind bounded
   exponential backoff with jitter; a request that exhausts its retries or
@@ -20,117 +17,28 @@ node (§II-B3) — without giving up the bit-identity contract:
   (:class:`repro.resilience.degrade.DegradePolicy`) — trading accuracy for
   forward progress exactly as the paper's low-bit operating points do.
 
+LMs survive faults in ``launch/engine.ContinuousLMEngine``, which commits
+decode epoch checkpoints and resumes from them after a kill.
+
 The resilient engine is deliberately a *per-node* story (mesh=None only)
 and dispatches buckets synchronously — recoverability instead of the base
-engine's double-buffered overlap.  Forward-progress work accounting lives
-in ``stats`` in logical decode steps, so a chaos run's efficiency is a
-deterministic function of the fault seed and maps directly onto
-``pim/intermittent.forward_progress`` (``benchmarks/bench_resilience.py``).
+engine's double-buffered overlap.
 """
 from __future__ import annotations
 
-import contextlib
 import hashlib
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.launch.engine import Bucket, LMRunner, Result, ServeEngine
-from .checkpoints import DecodeCheckpointer
+from repro.launch.engine import Bucket, Result, ServeEngine
 from .faults import (DEVICE_DROP, POWER_LOSS, SLOW_DISPATCH,
                      STAGING_CORRUPTION, DeviceDrop, FaultPlan, PowerLoss)
 
-# logical work-clock charge (in decode-step units) for non-decode hooks:
-# staging is a host copy (cheap), prefill one fused program over the prompt
+# logical work-clock charge of the staging hook, in dispatch units:
+# staging is a host copy (cheap)
 STAGING_DT = 0.25
-PREFILL_DT = 1.0
-
-
-class EpochLMRunner(LMRunner):
-    """LM runner whose decode is segmented into K-step checkpoint epochs.
-
-    Instead of one fused prefill+scan program per bucket (``LMRunner``),
-    the engine drives ``make_prefill_fn`` once and ``make_epoch_fn`` per
-    epoch, committing state between epochs.  Each epoch is still a jitted
-    ``lax.scan`` — the per-step dataflow is identical to ``launch/serve``'s
-    one-trace decode, only the scan boundary moves — and only two epoch
-    lengths ever compile (K and the tail remainder).
-
-    ``epoch_steps`` is the checkpoint period: the paper's P, in decode
-    steps.  Faulted-and-resumed output is bit-identical to a fault-free
-    run *of this same runner* (the epoch boundary is a program boundary,
-    so resume replays the exact program sequence on the exact state).
-    """
-
-    supports_epochs = True
-
-    def __init__(self, params, cfg, *, new_tokens: int, epoch_steps: int = 4,
-                 qmode: str = "serve", plan=None, model_plan=None):
-        super().__init__(params, cfg, new_tokens=new_tokens, qmode=qmode,
-                         plan=plan, model_plan=model_plan)
-        if epoch_steps < 1:
-            raise ValueError(f"epoch_steps must be >= 1, got {epoch_steps}")
-        self.epoch_steps = int(epoch_steps)
-
-    def epoch_schedule(self) -> tuple:
-        """Decode-step counts per epoch: K, K, ..., remainder."""
-        n, k = self.new_tokens - 1, self.epoch_steps
-        return tuple([k] * (n // k) + ([n % k] if n % k else []))
-
-    def _ctx(self):
-        return (self.model_plan.activate() if self.model_plan is not None
-                else contextlib.nullcontext())
-
-    def make_prefill_fn(self, key):
-        """(params, toks (B, S_p)) -> (grown cache, tok (B,1), pos)."""
-        from repro.launch.serve import greedy_token, grow_cache
-        from repro.models import transformer as T
-
-        _, prompt_len, new_tokens = key
-        cfg, plan, qmode = self.cfg, self.plan, self.qmode
-        slots = prompt_len + new_tokens
-
-        def fwd(params, toks):
-            with self._ctx():
-                logits, cache = T.prefill(params, cfg, plan, tokens=toks,
-                                          qmode=qmode)
-                cache = grow_cache(cache, prompt_len, slots)
-                first = greedy_token(logits, cfg.vocab)
-            return cache, first, jnp.asarray(prompt_len, jnp.int32)
-
-        return fwd
-
-    def make_epoch_fn(self, key, steps: int):
-        """(params, cache, tok, pos) -> (cache, tok, pos, chunk (B, steps))."""
-        from repro.launch.serve import make_decode_step
-
-        cfg, plan, qmode = self.cfg, self.plan, self.qmode
-
-        def fwd(params, cache, tok, pos):
-            with self._ctx():
-                step = make_decode_step(params, cfg, plan, qmode)
-                (cache, tok, pos), toks = jax.lax.scan(
-                    step, (cache, tok, pos), None, length=steps)
-            return cache, tok, pos, toks[:, :, 0].T
-
-        return fwd
-
-    def decode_state_template(self, key, batch: int, emitted: int) -> dict:
-        """Checkpoint-state structure rebuilt from config alone — nothing
-        volatile survives a reboot, so restore cannot depend on any live
-        cache object (shapes come from the stored arrays; the template
-        supplies structure and dtypes)."""
-        from repro.models import transformer as T
-
-        _, prompt_len, new_tokens = key
-        cache = T.init_cache(self.cfg, self.plan, batch,
-                             prompt_len + new_tokens)
-        return dict(cache=cache,
-                    tok=np.zeros((batch, 1), np.int32),
-                    pos=np.zeros((), np.int32),
-                    toks=np.zeros((batch, emitted), np.int32))
 
 
 class ResilientServeEngine(ServeEngine):
@@ -140,9 +48,6 @@ class ResilientServeEngine(ServeEngine):
     -------------------------------------
     fault_plan:      the seeded fault schedule (None -> fault-free, same
                      code path — the reference arm of bit-identity tests).
-    checkpoint_dir:  where decode epoch checkpoints commit; None disables
-                     micro-checkpointing (the volatile P=0 baseline: a kill
-                     restarts the whole bucket from prefill).
     max_retries:     kills a request survives before dead-lettering.
     backoff_base_s / backoff_max_s: exponential backoff bounds for
                      re-enqueued buckets (jittered; the engine's ``clock``
@@ -159,7 +64,7 @@ class ResilientServeEngine(ServeEngine):
     """
 
     def __init__(self, runner, *, fault_plan: FaultPlan | None = None,
-                 checkpoint_dir: str | None = None, max_retries: int = 3,
+                 max_retries: int = 3,
                  backoff_base_s: float = 0.01, backoff_max_s: float = 1.0,
                  deadline_s: float | None = None, degrade=None,
                  fallbacks=(), slow_dispatch_s: float = 0.0, seed: int = 0,
@@ -171,8 +76,6 @@ class ResilientServeEngine(ServeEngine):
                 "above the engine, one resilient engine per node")
         super().__init__(runner, **kw)
         self.faults = fault_plan if fault_plan is not None else FaultPlan(None)
-        self.ckpt = (DecodeCheckpointer(checkpoint_dir)
-                     if checkpoint_dir else None)
         self.max_retries = int(max_retries)
         self.backoff_base_s = backoff_base_s
         self.backoff_max_s = backoff_max_s
@@ -196,10 +99,7 @@ class ResilientServeEngine(ServeEngine):
         self.stats.update(
             faults=0, power_losses=0, device_drops=0, slow_dispatches=0,
             staging_retries=0, retries=0, dead_lettered=0, degrades=0,
-            recoveries=0,
-            prefills=0, resumes=0, epochs=0, commits=0, commit_s=0.0,
-            executed_steps=0, useful_steps=0, wasted_steps=0.0,
-            energy_pj=0.0)
+            recoveries=0, energy_pj=0.0)
 
     # -- queue side: retries are pre-admitted work --------------------------
 
@@ -254,7 +154,6 @@ class ResilientServeEngine(ServeEngine):
         """Idempotent re-enqueue of a killed bucket: bounded retries,
         exponential backoff with jitter, dead-letter on exhaustion."""
         now = self.clock()
-        survivors = []
         for req in bucket.requests:
             a = self._attempts.get(req.rid, 0) + 1
             self._attempts[req.rid] = a
@@ -267,10 +166,6 @@ class ResilientServeEngine(ServeEngine):
             delay *= 0.5 + self._rng.uniform()          # jitter [0.5, 1.5)
             self._retry.append((now + delay, req))
             self.stats["retries"] += 1
-            survivors.append(req)
-        if self.ckpt is not None and len(survivors) != len(bucket.requests):
-            # composition changed: the old tag can never be resumed
-            self.ckpt.purge(self._bucket_tag(bucket))
 
     def _maybe_degrade(self) -> None:
         if self.policy is None or self._active + 1 >= len(self._runners):
@@ -285,9 +180,6 @@ class ResilientServeEngine(ServeEngine):
         self._attempts.clear()   # fresh retry budget at the new operating point
         self.policy.reset()
         self.stats["degrades"] += 1
-        if self.ckpt is not None:
-            # every outstanding checkpoint names the retired plan fingerprint
-            self.ckpt.purge_all()
 
     def _maybe_recover(self) -> None:
         """Re-arm the primary plan once fault pressure has subsided: the
@@ -307,9 +199,6 @@ class ResilientServeEngine(ServeEngine):
         self._attempts.clear()   # fresh retry budget at the restored point
         self.policy.reset()
         self.stats["recoveries"] += 1
-        if self.ckpt is not None:
-            # outstanding checkpoints name the degraded plan fingerprint
-            self.ckpt.purge_all()
 
     @staticmethod
     def _relative_energy(old, new) -> float:
@@ -318,7 +207,7 @@ class ResilientServeEngine(ServeEngine):
         from repro.core.plan import plan_energy_pj
 
         def _e(r):
-            plan = getattr(r, "model_plan", None) or getattr(r, "plan", None)
+            plan = r.plan
             if plan is not None and hasattr(plan, "layers"):
                 return plan_energy_pj(plan)
             return 0.0
@@ -342,7 +231,6 @@ class ResilientServeEngine(ServeEngine):
                 time.sleep(self.slow_dispatch_s)
             return ev
         if ev.kind in (POWER_LOSS, DEVICE_DROP):
-            self.stats["wasted_steps"] += ev.offset
             FaultPlan.raise_for(ev)
         return ev
 
@@ -362,8 +250,6 @@ class ResilientServeEngine(ServeEngine):
             else:
                 live.append(req)
         if len(live) != len(bucket.requests):
-            if self.ckpt is not None:
-                self.ckpt.purge(self._bucket_tag(bucket))
             if not live:
                 return
             bucket = Bucket(bucket.key, live)
@@ -381,14 +267,9 @@ class ResilientServeEngine(ServeEngine):
     def _dispatch_bucket(self, bucket: Bucket) -> None:
         padded = self._pad_to(len(bucket.requests))
         dev = self._stage_checked(bucket, padded)
-        if getattr(self.runner, "supports_epochs", False):
-            host = self._run_epochs(bucket, padded, dev)
-        else:
-            self._fault_gate("dispatch", dt=1.0)
-            out = self._executable(bucket.key, padded)(self._params, dev)
-            host = np.asarray(out)
-            self.stats["executed_steps"] += 1
-            self.stats["useful_steps"] += 1
+        self._fault_gate("dispatch", dt=1.0)
+        host = np.asarray(self._executable(bucket.key, padded)(self._params,
+                                                              dev))
         self._record_results(bucket, padded, host)
 
     def _stage_checked(self, bucket: Bucket, padded: int):
@@ -416,73 +297,6 @@ class ResilientServeEngine(ServeEngine):
         self.stats["put_chunks"] += 1
         return jax.device_put(batch)
 
-    # -- epoch decode with micro-checkpoints --------------------------------
-
-    def _bucket_tag(self, bucket: Bucket) -> str:
-        fp = getattr(self.runner, "plan_fingerprint", lambda: None)()
-        return DecodeCheckpointer.tag(
-            (r.rid for r in bucket.requests), bucket.key, fp,
-            getattr(self.runner, "epoch_steps", 0))
-
-    def _prog(self, kind: str, key, padded: int, steps: int | None = None):
-        fp = getattr(self.runner, "plan_fingerprint", lambda: None)()
-        cache_key = ("resilient", kind, key, padded, steps, fp)
-        if cache_key not in self._fns:
-            if kind == "prefill":
-                fn = self.runner.make_prefill_fn(key)
-            else:
-                fn = self.runner.make_epoch_fn(key, steps)
-            self._fns[cache_key] = jax.jit(fn)
-        return self._fns[cache_key]
-
-    def _run_epochs(self, bucket: Bucket, padded: int, dev) -> np.ndarray:
-        r = self.runner
-        key = bucket.key
-        schedule = r.epoch_schedule()
-        tag = self._bucket_tag(bucket) if self.ckpt is not None else None
-        start_epoch, state = 0, None
-        if tag is not None:
-            restored = self.ckpt.restore(
-                tag, lambda emitted: r.decode_state_template(key, padded,
-                                                             emitted))
-            if restored is not None:
-                committed, s = restored
-                start_epoch = committed
-                state = (s["cache"], s["tok"], s["pos"], s["toks"])
-                self.stats["resumes"] += 1
-        if state is None:
-            self._fault_gate("prefill", dt=PREFILL_DT)
-            cache, tok, pos = self._prog("prefill", key, padded)(self._params,
-                                                                 dev)
-            state = (cache, tok, pos, tok)
-            self.stats["prefills"] += 1
-            if tag is not None:
-                self._commit(tag, 0, state)
-        for e in range(start_epoch, len(schedule)):
-            steps = schedule[e]
-            self._fault_gate("decode", dt=float(steps))
-            cache, tok, pos, toks = state
-            cache, tok, pos, chunk = self._prog("epoch", key, padded,
-                                                steps)(self._params, cache,
-                                                       tok, pos)
-            state = (cache, tok, pos, jnp.concatenate([toks, chunk], axis=1))
-            self.stats["executed_steps"] += steps
-            self.stats["epochs"] += 1
-            if tag is not None:
-                self._commit(tag, e + 1, state)
-        host = np.asarray(state[3])
-        self.stats["useful_steps"] += sum(schedule)
-        if tag is not None:
-            self.ckpt.purge(tag)
-        return host
-
-    def _commit(self, tag: str, epoch: int, state) -> None:
-        cache, tok, pos, toks = state
-        self.stats["commit_s"] += self.ckpt.commit(
-            tag, epoch, dict(cache=cache, tok=tok, pos=pos, toks=toks),
-            emitted=int(toks.shape[1]))
-        self.stats["commits"] += 1
-
     # -- harvest -------------------------------------------------------------
 
     def _record_results(self, bucket: Bucket, padded: int,
@@ -497,8 +311,7 @@ class ResilientServeEngine(ServeEngine):
         self.stats["dispatches"] += 1
         self.stats["requests"] += n
         self.stats["padded_rows"] += padded - n
-        plan = getattr(self.runner, "model_plan", None) \
-            or getattr(self.runner, "plan", None)
+        plan = self.runner.plan
         energy = 0.0
         if plan is not None and hasattr(plan, "layers"):
             from repro.core.plan import plan_energy_pj

@@ -5,9 +5,10 @@ battery-less node keeps making forward progress because the partial state
 it needs lives in non-volatile elements (§II-B3, Fig. 7).  The analytic
 side of that claim is ``pim/intermittent.forward_progress``; this module
 supplies the *executable* side — a seeded, reproducible schedule of fault
-events that :class:`repro.resilience.engine.ResilientServeEngine` polls at
-its hook points (staging, prefill, each decode epoch, single-shot
-dispatch).
+events that the serve engines poll at their hook points: the CNN
+:class:`repro.resilience.engine.ResilientServeEngine` at staging and
+dispatch, ``launch/engine.ContinuousLMEngine`` at each prefill chunk and
+each decode step.
 
 Faults are drawn on a **logical work clock** measured in decode steps, not
 wall time: every hook advances the clock by the amount of work it is about
@@ -24,7 +25,8 @@ Event kinds and who may draw them:
 ``power_loss``         the node browns out: everything volatile in the
                        current dispatch is lost (any site)
 ``device_drop``        the accelerator disappears mid-dispatch; host state
-                       survives (prefill/decode/dispatch)
+                       survives in the CNN engine, the LM engine reboots
+                       as for a power loss (prefill/decode/dispatch)
 ``slow_dispatch``      the dispatch stalls (brownout throttling) — latency
                        only, no state loss (prefill/decode/dispatch)
 ``staging_corruption`` the host->device copy is corrupted; detected by

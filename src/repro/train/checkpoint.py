@@ -158,10 +158,10 @@ class Checkpointer:
     def purge(self, prefix: str) -> int:
         """Remove every published checkpoint whose name starts with
         ``prefix``; returns how many were removed.  Prefix (not exact-tag)
-        matching on purpose: families of derived tags (e.g. the resilience
-        layer's ``dec<hash>`` composition tags) can be dropped wholesale
-        with their common stem.  Waits for any in-flight async save first
-        so a concurrent write cannot republish what was just purged."""
+        matching on purpose: a family of derived tags can be dropped
+        wholesale with its common stem.  Waits for any in-flight async
+        save first so a concurrent write cannot republish what was just
+        purged."""
         self.wait()
         n = 0
         for name in list(os.listdir(self.dir)):

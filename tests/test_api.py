@@ -234,32 +234,116 @@ def test_plans_carry_per_layer_cost_estimates():
     assert rt.cost == plan.layers[1].cost
 
 
-def test_lm_session_serve_matches_direct_plan():
+def _tiny_lm():
     from repro.configs import SINGLE, all_configs
-    from repro.launch.engine import LMRunner, ServeEngine
+    from repro.core.quant import W1A8
     from repro.models import transformer as T
 
     cfg = dataclasses.replace(
         all_configs()["smollm-360m"].smoke(
             n_layers=2, d_model=64, n_heads=2, n_kv_heads=1, d_ff=128,
             vocab=64, head_dim=32),
-        quant=dataclasses.replace(
-            __import__("repro.core.quant", fromlist=["W1A8"]).W1A8,
-            engine="auto"))
+        quant=dataclasses.replace(W1A8, engine="auto"))
     params, _ = T.init_lm(jax.random.PRNGKey(0), cfg, SINGLE)
+    return cfg, params
+
+
+def test_lm_session_serve_matches_direct_plan():
+    """The facade's LM deployment serves what a continuous engine built by
+    hand over a directly compiled plan serves."""
+    from repro.launch.engine import ContinuousLMEngine
+
+    cfg, params = _tiny_lm()
     prompts = [np.random.RandomState(i).randint(0, cfg.vocab, size=(8,))
                .astype(np.int32) for i in range(3)]
     compiled = api.build(cfg, params=params).compile(batch_hints=(4,),
                                                      prompt_len=8)
     got = compiled.serve(max_batch=4, new_tokens=5).predict(prompts)
     direct_plan = P.compile_lm(params, cfg, batch_hints=(4,), prompt_len=8)
-    ref = ServeEngine(LMRunner(None, cfg, new_tokens=5,
-                               model_plan=direct_plan),
-                      max_batch=4).serve(prompts)
+    ref = ContinuousLMEngine(None, cfg, num_slots=4, new_tokens=5,
+                             model_plan=direct_plan).serve(prompts)
     for g, r in zip(got, ref):
         np.testing.assert_array_equal(g, r.value)
     with pytest.raises(P.PlanError, match="CNN"):
         compiled.simulate(target="sot_mram")
+
+
+def test_lm_serve_mixed_lengths_in_one_continuous_batch():
+    """Prompts of 3 to 20 tokens (one or two prefill chunks) with horizons
+    1 to 9 share one continuous batch, and each request's tokens equal
+    serving it alone."""
+    cfg, params = _tiny_lm()
+    lens, horizons = (5, 20, 8, 3, 12), (3, 9, 1, 6, 4)
+    payloads = [(np.random.RandomState(i).randint(0, cfg.vocab, size=(n,))
+                 .astype(np.int32), h)
+                for i, (n, h) in enumerate(zip(lens, horizons))]
+    compiled = api.build(cfg, params=params).compile(batch_hints=(1, 3),
+                                                     prompt_len=8)
+    dep = compiled.serve(max_batch=3)
+    got = dep.predict(payloads)
+    # the requests overlapped: fewer decode steps than one after another
+    assert dep.stats["steps"] < sum(h - 1 for h in horizons)
+    assert dep.stats["prefill_chunks"] == 6
+    alone = compiled.serve(max_batch=3)
+    for g, p, h in zip(got, payloads, horizons):
+        assert g.shape == (h,)
+        [ref] = alone.predict([p])
+        np.testing.assert_array_equal(g, ref)
+
+
+def test_lm_serve_refuses_mesh_and_fallback():
+    """The continuous engine serves on one device and has no degrade
+    path: ``mesh`` and ``fallback`` on an LM plan raise PlanError, plain
+    or resilient."""
+    from repro.resilience import ResilienceConfig
+
+    cfg, params = _tiny_lm()
+    compiled = api.build(cfg, params=params).compile(prompt_len=8)
+    for kw in (dict(mesh=object()), dict(fallback=compiled),
+               dict(fallback=compiled, resilience=ResilienceConfig())):
+        with pytest.raises(P.PlanError, match="one device"):
+            compiled.serve(**kw)
+
+
+@pytest.mark.parametrize("arch", ["deepseek-moe-16b", "rwkv6-1.6b",
+                                  "recurrentgemma-9b"])
+def test_lm_serve_refuses_non_attention_blocks(arch):
+    """The continuous engine's paged KV cache holds attention layers only:
+    serving an MoE, RWKV or recurrent/local-window model raises PlanError
+    naming its block kinds, before any engine is built."""
+    from repro.configs import all_configs
+
+    cfg, params = _tiny_lm()
+    compiled = api.build(cfg, params=params).compile(prompt_len=8)
+    other = all_configs()[arch]
+    kinds = sorted(set(other.blocks_pattern) - {"attn"})
+    foreign = dataclasses.replace(compiled, model=api.build(other))
+    with pytest.raises(P.PlanError, match="attention layers only") as e:
+        foreign.serve()
+    assert str(kinds) in str(e.value)
+
+
+def test_predict_raises_for_a_request_without_result():
+    """``predict`` returns one value per payload: a request that was
+    dead-lettered (no retries left after a kill) raises instead of
+    shortening the list."""
+    from repro.resilience import FaultPlan, ResilienceConfig
+
+    spec = svhn_cnn_spec(8)
+    params, _ = init_cnn(jax.random.PRNGKey(0), spec)
+    compiled = api.build(spec, W1A4, params=params, img_hw=16).compile(
+        batch_hints=(1, 2))
+    imgs = [np.random.RandomState(i).uniform(size=(16, 16, 3))
+            .astype(np.float32) for i in range(2)]
+    dep = compiled.serve(max_batch=2, resilience=ResilienceConfig(
+        fault_plan=FaultPlan.scripted([("dispatch", 0, "power_loss")]),
+        max_retries=0))
+    with pytest.raises(RuntimeError, match=r"\[0, 1\] have no result"):
+        dep.predict(imgs)
+    got = dep.predict(imgs)                 # the next round is served
+    ref = compiled.serve(max_batch=2).predict(imgs)
+    for g, r in zip(got, ref, strict=True):
+        np.testing.assert_array_equal(g, r)
 
 
 # ---------------------------------------------------------------------------

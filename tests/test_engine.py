@@ -3,9 +3,8 @@
 Headline contract: batching is invisible — a request's result is
 bit-identical whether it ran alone (sequential per-request dispatch), in a
 full bucket, in a ragged padded bucket, or sharded across devices, for
-every conv engine the dispatcher can pick.  Plus the widen_cache
-regression (structural sequence-axis identification) that the engine's LM
-path depends on.
+every conv engine the dispatcher can pick.  Plus the LM facade: an LM
+plan serves on the continuous engine, with nothing added numerically.
 """
 import dataclasses
 import os
@@ -20,7 +19,7 @@ import pytest
 from repro.configs import SINGLE, all_configs
 from repro.core.quant import PAPER_CONFIGS, W1A4
 from repro.launch import engine as engine_mod
-from repro.launch.engine import (BucketBatcher, CNNRunner, LMRunner, QueueFull,
+from repro.launch.engine import (BucketBatcher, CNNRunner, QueueFull,
                                  Request, ServeEngine, run_offered_load)
 from repro.models import transformer as T
 from repro.core.prequant import prequantize_cnn_params
@@ -200,19 +199,11 @@ def test_cnn_chunked_staging_one_program_per_padded_batch(monkeypatch):
     assert all(fn._cache_size() == 1 for fn in eng._fns.values())
 
 
-@pytest.mark.parametrize("kind", ["cnn", "lm", "resilient"])
+@pytest.mark.parametrize("kind", ["cnn", "resilient"])
 def test_small_and_checked_buckets_stage_in_one_put(monkeypatch, kind):
-    """A sub-threshold CNN bucket, an LM bucket (int32 tokens, kilobytes)
-    and ``ResilientServeEngine``'s checked staging each issue exactly one
-    put per bucket and serve what the plain engine serves."""
-    if kind == "lm":
-        cfg, params = _lm_setup()
-        prompts = [np.random.RandomState(i).randint(0, cfg.vocab, size=(8,))
-                   .astype(np.int32) for i in range(5)]
-        eng = ServeEngine(LMRunner(params, cfg, new_tokens=3), max_batch=4)
-        eng.serve(prompts)
-        assert eng.stats["put_chunks"] == eng.stats["dispatches"] == 2
-        return
+    """A sub-threshold CNN bucket and ``ResilientServeEngine``'s checked
+    staging each issue exactly one put per bucket and serve what the plain
+    engine serves."""
     ref = _cnn_engine(W1A4, 4).serve(IMGS)
     if kind == "cnn":
         eng = _cnn_engine(W1A4, 4)
@@ -385,7 +376,7 @@ def test_offered_load_splits_queue_wait_from_service():
 
 
 # ---------------------------------------------------------------------------
-# LM path: bucketing by prompt length, batched == sequential tokens
+# LM path: the facade serves an LM plan on the continuous engine
 # ---------------------------------------------------------------------------
 
 def _lm_setup():
@@ -399,114 +390,36 @@ def _lm_setup():
 
 
 def test_lm_engine_exact_vs_direct_forward_same_composition():
-    """The engine layer adds NOTHING numerically: collate/pad/stage/split
-    around a bucket reproduces a direct jitted call on the same padded
-    batch bit-for-bit (full bucket of 4 and ragged padded tail of 1).
+    """The facade adds NOTHING numerically: ``compiled.serve()`` on an LM
+    plan builds a continuous engine whose tokens equal a hand-built
+    ``ContinuousLMEngine`` over the same plan, request for request, at the
+    same composition (5 requests on 4 slots: a full batch, then one
+    admitted into a freed slot), with the same dispatch counts."""
+    from repro import api
+    from repro.launch.engine import ContinuousLMEngine
 
-    Exact per-request-vs-batched token equality is a model-numerics
-    property, not an engine property: on CPU, XLA's reduction strategy
-    varies with the batch dimension and activation quantization amplifies
-    those ulps into level flips (same reason bench_serve reports rather
-    than asserts loop-vs-scan token match).  The integer-engine CNN path
-    above carries the strict batched==sequential bit-identity contract.
-    """
     cfg, params = _lm_setup()
     prompts = [np.random.RandomState(i).randint(0, cfg.vocab, size=(8,))
                .astype(np.int32) for i in range(5)]
-    runner = LMRunner(params, cfg, new_tokens=6)
-    eng = ServeEngine(runner, max_batch=4)
-    res = eng.serve(prompts)  # buckets: [0..3] and padded [4]
-    assert eng.stats["dispatches"] == 2
-    fwd = jax.jit(runner.make_forward(runner.shape_key(prompts[0])))
-    direct4 = np.asarray(fwd(params, jnp.asarray(np.stack(prompts[:4]))))
-    direct1 = np.asarray(fwd(params, jnp.asarray(prompts[4])[None]))
-    for i in range(4):
-        np.testing.assert_array_equal(res[i].value, direct4[i])
-    np.testing.assert_array_equal(res[4].value, direct1[0])
+    compiled = api.build(cfg, params=params).compile(batch_hints=(1, 4),
+                                                     prompt_len=8)
+    dep = compiled.serve(max_batch=4, new_tokens=6)
+    assert isinstance(dep.engine, ContinuousLMEngine)
+    assert dep.engine.num_slots == 4
+    res = dep.engine.serve(prompts)
+    hand = ContinuousLMEngine(None, cfg, num_slots=4, new_tokens=6,
+                              model_plan=compiled.plan)
+    ref = hand.serve(prompts)
+    assert dep.stats == hand.stats
+    for a, b in zip(res, ref):
+        np.testing.assert_array_equal(a.value, b.value)
     assert all(r.value.shape == (6,) for r in res)
     # tokens come from the REAL vocab, never the padded unembed tail
     assert all(int(r.value.max()) < cfg.vocab for r in res)
-    # engine dispatch is deterministic: a fresh engine reproduces exactly
-    res2 = ServeEngine(LMRunner(params, cfg, new_tokens=6),
-                       max_batch=4).serve(prompts)
-    for a, b in zip(res, res2):
-        np.testing.assert_array_equal(a.value, b.value)
-
-
-def test_lm_engine_buckets_by_prompt_len():
-    cfg, params = _lm_setup()
-    p8 = [np.random.RandomState(i).randint(0, cfg.vocab, size=(8,))
-          .astype(np.int32) for i in range(2)]
-    p12 = [np.random.RandomState(9).randint(0, cfg.vocab, size=(12,))
-           .astype(np.int32)]
-    runner = LMRunner(params, cfg, new_tokens=4)
-    eng = ServeEngine(runner, max_batch=4)
-    res = eng.serve([p8[0], p12[0], p8[1]])
-    assert eng.stats["dispatches"] == 2  # prompt lengths never co-batch
-    # each bucket reproduces the direct forward at its own composition
-    fwd8 = jax.jit(runner.make_forward(runner.shape_key(p8[0])))
-    fwd12 = jax.jit(runner.make_forward(runner.shape_key(p12[0])))
-    d8 = np.asarray(fwd8(params, jnp.asarray(np.stack(p8))))
-    d12 = np.asarray(fwd12(params, jnp.asarray(p12[0])[None]))
-    np.testing.assert_array_equal(res[0].value, d8[0])
-    np.testing.assert_array_equal(res[2].value, d8[1])
-    np.testing.assert_array_equal(res[1].value, d12[0])
-
-
-# ---------------------------------------------------------------------------
-# widen_cache regression: structural sequence axis, not size coincidence
-# ---------------------------------------------------------------------------
-
-def test_widen_cache_ignores_size_coincidences():
-    """State tensors whose axis 2 merely EQUALS the prompt length (rec.h
-    lru width, rec.conv taps, head_dim) must pass through untouched; only
-    attention k/v/pos widen.  Pre-fix, widen_cache padded rec.h (and any
-    other ndim>=3, shape[2]==prompt_len tensor), corrupting decode."""
-    from repro.launch.serve import widen_cache
-
-    S_p = 16
-    cfg = all_configs()["recurrentgemma-9b"].smoke(
-        n_layers=3, d_model=64, n_heads=2, n_kv_heads=1, d_ff=128, vocab=64,
-        head_dim=S_p,       # head_dim == prompt_len (the issue's coincidence)
-        lru_width=S_p,      # rec.h axis 2 == prompt_len -> pre-fix corruption
-        window=8)
-    params, _ = T.init_lm(jax.random.PRNGKey(0), cfg, SINGLE)
-    toks = jax.random.randint(jax.random.PRNGKey(1), (2, S_p), 0, cfg.vocab)
-    logits, cache = T.prefill(params, cfg, SINGLE, tokens=toks)
-    assert cache["rec"]["h"].shape[2] == S_p  # the trap is armed
-    with pytest.warns(DeprecationWarning, match="grow_cache"):
-        w = widen_cache(cache, S_p, S_p + 8)
-    # recurrent state: untouched
-    assert w["rec"]["h"].shape == cache["rec"]["h"].shape
-    assert w["rec"]["conv"].shape == cache["rec"]["conv"].shape
-    # attention cache: widened along the slot axis, new pos slots empty
-    assert w["attn_local"]["k"].shape[2] == S_p + 8
-    assert w["attn_local"]["v"].shape[2] == S_p + 8
-    assert bool((np.asarray(w["attn_local"]["pos"])[:, :, S_p:] == -1).all())
-    # and the widened cache actually decodes
-    tok = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
-    lg, _ = T.decode_step(params, w, tok, jnp.asarray(S_p, jnp.int32), cfg,
-                          SINGLE)
-    assert lg.shape[0] == 2 and bool(jnp.isfinite(lg).all())
-
-
-def test_widen_cache_dense_head_dim_collision():
-    """Dense attn cache with head_dim == kv_heads == prompt_len: every
-    shape-coincidence at once; k/v widen exactly once, at axis 2."""
-    from repro.launch.serve import widen_cache
-
-    S_p = 4
-    cfg = all_configs()["smollm-360m"].smoke(
-        n_layers=2, d_model=64, n_heads=4, n_kv_heads=S_p, d_ff=128,
-        vocab=64, head_dim=S_p)
-    params, _ = T.init_lm(jax.random.PRNGKey(0), cfg, SINGLE)
-    toks = jax.random.randint(jax.random.PRNGKey(1), (2, S_p), 0, cfg.vocab)
-    _, cache = T.prefill(params, cfg, SINGLE, tokens=toks)
-    assert cache["attn"]["k"].shape[2:] == (S_p, S_p, S_p)
-    with pytest.warns(DeprecationWarning, match="grow_cache"):
-        w = widen_cache(cache, S_p, S_p + 3)
-    assert w["attn"]["k"].shape == cache["attn"]["k"].shape[:2] + (S_p + 3,
-                                                                   S_p, S_p)
+    # serving is deterministic: a fresh deployment reproduces exactly
+    again = compiled.serve(max_batch=4, new_tokens=6).predict(prompts)
+    for a, b in zip(res, again):
+        np.testing.assert_array_equal(a.value, b)
 
 
 # ---------------------------------------------------------------------------

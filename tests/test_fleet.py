@@ -3,7 +3,8 @@
 Covers: trace determinism and serialization, the fluid node walk's
 physics invariants against closed forms, fleet aggregation, the
 simulator-vs-live-engine validation contract (the engine-accounting
-mirror AND one real ``ResilientServeEngine`` replay), and the co-design
+mirror AND one real continuous-engine replay through the resilient
+facade), and the co-design
 search (SLO enforcement, baseline win, Pareto bookkeeping).
 """
 import json
@@ -13,7 +14,7 @@ import pytest
 
 from repro.fleet import (DAY_S, HarvestTrace, NodeConfig, TraceSpec,
                          assign_slos, candidate_space, codesign,
-                         epoch_schedule, fleet_report, generate_fleet,
+                         fleet_report, generate_fleet,
                          make_trace, measured_efficiency, outage_faultplan,
                          predict_engine_stats, rescale_outages,
                          simulate_fleet, simulate_node)
@@ -199,47 +200,113 @@ def test_fleet_report_aggregates_and_archetypes():
 # Discrete arm: engine mirror + live validation
 # ---------------------------------------------------------------------------
 
-def test_sim_constants_mirror_engine():
-    """sim.py keeps jax out of the fluid path by mirroring the engine's
-    poll charges as local constants — pin them to the real ones."""
-    from repro.resilience import engine as real
+def _live_lm(epoch_steps: int, ckdir, fault_plan=None, max_batch: int = 4):
+    """The continuous engine live_validation replays through: the same
+    tiny smoke LM, built by the resilient facade."""
+    import dataclasses
 
-    assert fleet_sim.STAGING_DT == real.STAGING_DT
-    assert fleet_sim.PREFILL_DT == real.PREFILL_DT
+    import jax
+
+    from repro import api
+    from repro.configs import SINGLE, all_configs
+    from repro.core.quant import PAPER_CONFIGS
+    from repro.models import transformer as T
+    from repro.resilience import ResilienceConfig
+
+    cfg = dataclasses.replace(
+        all_configs()["smollm-360m"].smoke(
+            n_layers=2, d_model=64, n_heads=2, n_kv_heads=1, d_ff=128,
+            vocab=64, head_dim=32),
+        quant=PAPER_CONFIGS["w1a8"])
+    params, _ = T.init_lm(jax.random.PRNGKey(0), cfg, SINGLE)
+    compiled = api.build(cfg, params=params).compile(
+        batch_hints=(1, max_batch), prompt_len=8)
+    return compiled.serve(max_batch=max_batch, new_tokens=7,
+                          resilience=ResilienceConfig(
+                              fault_plan=fault_plan, checkpoint_dir=ckdir,
+                              epoch_steps=epoch_steps)).engine
 
 
-def test_epoch_schedule_mirror():
-    from repro.resilience import EpochLMRunner
+_PROMPTS = [np.random.RandomState(i).randint(0, 64, size=(8,))
+            .astype(np.int32) for i in range(8)]
 
-    for nt, es in ((7, 2), (8, 3), (5, 5), (2, 4)):
-        r = object.__new__(EpochLMRunner)   # schedule reads only these two
-        r.new_tokens, r.epoch_steps = nt, es
-        assert epoch_schedule(nt, es) == r.epoch_schedule()
+
+def test_sim_constants_mirror_engine(tmp_path):
+    """sim.py keeps jax out of the fluid path by mirroring the continuous
+    engine's poll charges and page geometry as local constants — pin them
+    to a live engine: its fault clock advances by exactly PREFILL_DT per
+    prefill chunk plus DECODE_DT per decode step."""
+    faults = FaultPlan(None)
+    eng = _live_lm(2, str(tmp_path), fault_plan=faults)
+    eng.serve(_PROMPTS)
+    s = eng.stats
+    assert (eng.page_size, eng.pool.num_pages, eng.chunk) == (
+        fleet_sim.PAGE_SIZE, fleet_sim.NUM_PAGES, fleet_sim.PAGE_SIZE)
+    assert faults._t == (fleet_sim.PREFILL_DT * s["prefill_chunks"]
+                         + fleet_sim.DECODE_DT * s["steps"])
+    assert faults._site_calls == {"prefill": s["prefill_chunks"],
+                                  "decode": s["steps"]}
+
+
+def test_epoch_schedule_mirror(tmp_path):
+    """The mirror's commit schedule matches a live engine's for epoch
+    lengths from one step to longer than any request."""
+    for k in (1, 2, 3, 7):
+        eng = _live_lm(k, str(tmp_path / f"k{k}"))
+        eng.serve(_PROMPTS)
+        p = predict_engine_stats(FaultPlan(None), n_requests=8, new_tokens=7,
+                                 epoch_steps=k, max_batch=4)
+        for key in ("commits", "steps", "prefill_chunks", "dispatches"):
+            assert p[key] == eng.stats[key], (k, key)
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 6])
+def test_mirror_commit_schedule(k):
+    """One wave of four requests (6 decode steps): a commit lands before
+    decode steps 0, k, 2k, ... and a kill during step j resumes from the
+    last of them, so exactly j mod k steps are redone and no prompt is
+    prefilled again."""
+    kw = dict(n_requests=4, new_tokens=7, epoch_steps=k, max_batch=4)
+    free = predict_engine_stats(FaultPlan(None), **kw)
+    assert free["commits"] == len(range(0, 6, k))
+    assert free["steps"] == 6 and free["prefill_chunks"] == 4
+    for j in range(6):
+        # the work clock reads 4 (prefills) + j when decode step j starts
+        s = predict_engine_stats(outage_faultplan([4.0 + j + 0.5]), **kw)
+        assert s["power_losses"] == 1 and s["completed"] == 4
+        assert s["steps"] == 6 + j % k
+        assert s["prefill_chunks"] == 4
+        assert s["wasted_steps"] == 0.5
 
 
 def test_predict_engine_stats_fault_free():
     s = predict_engine_stats(FaultPlan(None), n_requests=8, new_tokens=7,
                              epoch_steps=2, max_batch=4)
-    sched = epoch_schedule(7, 2)
-    assert s["prefills"] == s["dispatches"] == 2
-    assert s["requests"] == 8 and s["faults"] == 0 and s["resumes"] == 0
-    assert s["useful_steps"] == s["executed_steps"] == 2 * sum(sched)
-    assert s["commits"] == 2 * (1 + len(sched))
+    # two waves of four: 4 prefill chunks and 6 decode steps each
+    assert s["prefill_chunks"] == 8 and s["steps"] == 12
+    assert s["dispatches"] == s["useful_dispatches"] == 20
+    assert s["requests"] == s["completed"] == 8 and s["power_losses"] == 0
+    assert s["commits"] == 6           # before steps 0, 2, 4, 6, 8, 10
     assert measured_efficiency(s) == pytest.approx(1.0)
 
 
 def test_predict_engine_stats_timeline_kills():
-    """A mid-decode power loss wastes the partial window, requeues the
-    bucket, and the resumed attempt skips prefill (checkpoint restore)."""
-    plan = outage_faultplan([2.0])       # dies inside the first decode epoch
+    """A mid-decode power loss wastes the partial window and resumes from
+    the last commit; a loss before the first commit restarts cold with
+    every request enqueued again, and all still complete."""
+    plan = outage_faultplan([6.0])       # during decode step 2
     s = predict_engine_stats(plan, n_requests=4, new_tokens=7,
                              epoch_steps=2, max_batch=4)
-    assert s["power_losses"] == 1 and s["retries"] == 4
-    assert s["resumes"] == 1             # second attempt restores, no prefill
-    assert s["prefills"] == 1
-    assert s["useful_steps"] == sum(epoch_schedule(7, 2))
-    assert 0 < s["wasted_steps"] <= 2.0
+    assert s["power_losses"] == 1 and s["completed"] == 4
+    assert s["prefill_chunks"] == 4      # resumed, not prefilled again
+    assert s["steps"] == 6 + 1           # step 2 redone from the commit
+    assert 0 < s["wasted_steps"] <= 1.0
     assert measured_efficiency(s) < 1.0
+    cold = predict_engine_stats(outage_faultplan([1.5]), n_requests=4,
+                                new_tokens=7, epoch_steps=2, max_batch=4)
+    assert cold["power_losses"] == 1 and cold["completed"] == 4
+    assert cold["prefill_chunks"] == 1 + 4   # one chunk ran before the loss
+    assert cold["steps"] == 6 and cold["commits"] == 3
 
 
 def test_outage_faultplan_json_shared_format():
@@ -264,18 +331,28 @@ def test_rescale_outages():
 @pytest.mark.slow
 def test_live_validation_matches_engine(tmp_path):
     """THE acceptance-criteria contract: the simulator's engine-accounting
-    mirror matches a real ResilientServeEngine replay of an outage
-    timeline — integer counters exactly, floats within tol."""
+    mirror matches a real continuous-engine replay of an outage timeline —
+    integer counters exactly, floats within tol, tokens as without
+    faults."""
     from repro.fleet import live_validation
 
+    # the first outage comes during the first prefills, before any commit
+    # (a cold restart), the second mid-decode after one
     v = live_validation([3.0, 9.5], checkpoint_dir=str(tmp_path),
                         n_requests=8, new_tokens=7, epoch_steps=2,
                         max_batch=4, tol=1e-6)
     assert v["ok"], v["deltas"]
     assert v["measured"]["power_losses"] == 2
     assert v["completed"] == 8 and v["dead_letters"] == 0
+    assert v["bit_identical"]
     assert all(d == 0 for k, d in v["deltas"].items()
                if k in fleet_sim._VALIDATE_INT_KEYS)
+    # both outages after the first commit (decode steps 2 and 7)
+    w = live_validation([6.5, 13.5], checkpoint_dir=str(tmp_path / "b"),
+                        n_requests=8, new_tokens=7, epoch_steps=2,
+                        max_batch=4, tol=1e-6)
+    assert w["ok"], w["deltas"]
+    assert w["completed"] == 8 and w["measured"]["power_losses"] == 2
 
 
 # ---------------------------------------------------------------------------

@@ -306,17 +306,19 @@ def test_lm_plan_bit_identical_and_scoped(tmp_path):
 
 
 def test_lm_runner_with_model_plan_matches_legacy():
+    """The continuous engine over a compiled plan serves the same tokens
+    as over prequantized params with per-trace engine dispatch."""
     cfg, params, T, SINGLE = _lm_setup()
-    from repro.launch.engine import LMRunner, ServeEngine
+    from repro.launch.engine import ContinuousLMEngine
     from repro.models.layers import prequantize_params
 
     prompts = [np.random.RandomState(i).randint(0, cfg.vocab, size=(8,))
                .astype(np.int32) for i in range(3)]
     plan = P.compile_lm(params, cfg, batch_hints=(4,), prompt_len=8)
-    res = ServeEngine(LMRunner(None, cfg, new_tokens=5, model_plan=plan),
-                      max_batch=4).serve(prompts)
-    legacy = ServeEngine(LMRunner(prequantize_params(params, cfg), cfg,
-                                  new_tokens=5), max_batch=4).serve(prompts)
+    res = ContinuousLMEngine(None, cfg, num_slots=4, new_tokens=5,
+                             model_plan=plan).serve(prompts)
+    legacy = ContinuousLMEngine(prequantize_params(params, cfg), cfg,
+                                num_slots=4, new_tokens=5).serve(prompts)
     for a, b in zip(res, legacy):
         np.testing.assert_array_equal(a.value, b.value)
 

@@ -108,8 +108,12 @@ def test_serve_prefill_then_decode_consistency():
     B, S_p, S_d = 2, 8, 4
     toks = jax.random.randint(key, (B, S_p + S_d), 0, cfg.vocab)
     logits_p, cache = T.prefill(params, cfg, SINGLE, tokens=toks[:, :S_p])
-    from repro.launch.serve import grow_cache
-    cache = grow_cache(cache, S_p, S_p + S_d)
+    # pad the KV slot axis (2) to the decode horizon; new slots are empty
+    cache = {kind: {k: jnp.pad(t, [(0, 0), (0, 0), (0, S_d)]
+                                + [(0, 0)] * (t.ndim - 3),
+                                constant_values=-1 if k == "pos" else 0)
+                    for k, t in entry.items()}
+             for kind, entry in cache.items()}
     outs = []
     for t in range(S_d):
         lg, cache = T.decode_step(params, cache, toks[:, S_p + t: S_p + t + 1],
@@ -119,6 +123,25 @@ def test_serve_prefill_then_decode_consistency():
     fwd, _, _ = T.forward(params, cfg, SINGLE, tokens=toks, mode="train")
     np.testing.assert_allclose(dec, np.asarray(fwd[:, S_p:]), atol=2e-2,
                                rtol=1e-2)
+
+
+@pytest.mark.parametrize("mode,expect", [
+    ([], "generated 2x3 tokens"),
+    (["--chaos-mtbf", "12", "--epoch-steps", "2", "--requests", "4"],
+     "bit-identical to fault-free: True"),
+])
+def test_serve_cli_serves_through_facade(monkeypatch, capsys, mode, expect):
+    """``python -m repro.launch.serve`` compiles a plan and serves on the
+    continuous engine, plain or under faults through the resilient
+    facade."""
+    from repro.launch import jit_cache, serve
+
+    monkeypatch.setattr(jit_cache, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "smollm-360m", "--quant", "w1a8", "--batch", "2",
+        "--prompt-len", "8", "--new-tokens", "3", *mode])
+    serve.main()
+    assert expect in capsys.readouterr().out
 
 
 def test_prequantized_serving_matches_runtime_quant():
